@@ -11,6 +11,11 @@ PR's perf numbers are recorded instead of scrolling away in logs.
 
 ``--fast`` mirrors ``benchmarks.run --fast`` (CI-friendly sizes);
 ``--only NAME`` runs a single benchmark.
+
+Everything runs in this one process, which holds the device.  The
+cross-process warm-start benchmark starts child processes that need the
+device themselves, so it is not run from here: run it on its own with
+``python benchmarks/warm_start.py``.
 """
 
 from __future__ import annotations
@@ -31,7 +36,7 @@ os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 def _jobs(fast: bool):
     from . import (allreduce, fft, hrelation, messages, pagerank,
                    program_replay, roofline, schedule_search,
-                   serve_latency, warm_start)
+                   serve_latency)
     return {
         "scheduler": lambda: schedule_search.main(),
         "hrelation": lambda: hrelation.main(),
@@ -45,7 +50,6 @@ def _jobs(fast: bool):
         "roofline": lambda: roofline.main(),
         "overlap": lambda: program_replay.main(compiled=False),
         "compiled_replay": lambda: program_replay.compiled_replay_main(),
-        "warm_start": lambda: warm_start.main(),
         "serve": lambda: serve_latency.main(
             n_requests=40 if fast else 120),
     }
@@ -82,6 +86,8 @@ def main() -> None:
                     help="write BENCH_<name>.json files into DIR")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     meta = _meta()
     out_dir = args.json
     if out_dir:

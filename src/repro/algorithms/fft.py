@@ -130,10 +130,12 @@ def bsp_fft_spmd(ctx: LPFContext, x_local: jnp.ndarray, n: int, *,
         Zk = ctx.tensor(dst).reshape(p, w)      # [s, k2_local]
         ctx.deregister(src)
 
-        # (3) p-point DFTs across s as a dense twiddle matmul (MXU-friendly)
+        # (3) p-point DFTs across s as a dense twiddle matmul (MXU-friendly;
+        # full f32 precision — the TPU's default matmul rounds to bf16)
         k1 = np.arange(p)
         Wp = np.exp(sign * 2j * np.pi * np.outer(k1, k1) / p).astype(ctype)
-        Y = jnp.einsum("ts,sk->tk", jnp.asarray(Wp), Zk)   # [k1, k2_local]
+        Y = jnp.einsum("ts,sk->tk", jnp.asarray(Wp), Zk,
+                       precision=jax.lax.Precision.HIGHEST)  # [k1, k2_local]
 
         if not ordered:
             ctx.deregister(dst)
@@ -169,7 +171,9 @@ def bsp_fft(mesh: jax.sharding.Mesh, x: jnp.ndarray, *,
     axes = tuple(axes) if axes is not None else tuple(mesh.axis_names)
     p = int(np.prod([mesh.shape[a] for a in axes]))
     n = int(x.shape[0])
-    xc = x.reshape(n // p, p).T.reshape(-1)  # cyclic layout, pid-major
+    # cyclic layout, pid-major; strided slices, because an [n/p, p]
+    # view would pad its p-wide minor dimension to a full TPU lane tile
+    xc = jnp.concatenate([x[s::p] for s in range(p)])
 
     def spmd(ctx, s, pp, xt):
         xl = xt.reshape(pp, n // pp)[s]

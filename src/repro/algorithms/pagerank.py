@@ -191,15 +191,17 @@ def dataflow_pagerank(edges: np.ndarray, n: int, iters: int,
 def reference_pagerank(edges: np.ndarray, n: int, alpha: float = 0.85,
                        tol: float = 1e-10, max_iter: int = 500
                        ) -> Tuple[np.ndarray, int]:
-    """Dense numpy oracle with dangling handling (test reference)."""
-    A = np.zeros((n, n), np.float64)
+    """Sparse float64 numpy oracle with dangling handling (test
+    reference): ``A[d, s] = 1/outdeg(s)`` once per distinct edge."""
     outdeg = np.bincount(edges[:, 0], minlength=n)
-    for s, d in edges:
-        A[d, s] = 1.0 / outdeg[s]
-    dangling = (outdeg == 0).astype(np.float64)
+    keys = np.unique(edges[:, 0].astype(np.int64) * n + edges[:, 1])
+    src, dst = keys // n, keys % n
+    w = 1.0 / outdeg[src]
+    dangling = outdeg == 0
     r = np.full(n, 1.0 / n)
     for it in range(max_iter):
-        r_new = alpha * (A @ r + np.dot(dangling, r) / n) + (1 - alpha) / n
+        r_new = alpha * (np.bincount(dst, weights=w * r[src], minlength=n)
+                         + r[dangling].sum() / n) + (1 - alpha) / n
         if np.abs(r_new - r).sum() < tol:
             return r_new, it + 1
         r = r_new

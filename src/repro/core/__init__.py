@@ -30,7 +30,7 @@ from .faultpoints import InjectedFault
 from .hlo_analysis import (CollectiveStats, RooflineTerms, parse_collectives,
                            roofline_terms)
 from .machine import (CPU_HOST, TPU_V5E, TPU_V5P, HardwareModel, LinkModel,
-                      LPFMachine, probe)
+                      LPFMachine, hardware_for, probe)
 from .memslot import Slot, SlotRegistry
 from .persist import PersistentStore, PersistError, steps_from_signature
 from .program import (CompiledProgram, OptimizedStep, ProgramCache,
@@ -58,7 +58,7 @@ __all__ = [
     "LPFTransientError", "classify", "InjectedFault",
     "LPF_SUCCESS", "LPF_ERR_OUT_OF_MEMORY", "LPF_ERR_FATAL",
     "LPF_ERR_TRANSIENT",
-    "HardwareModel", "LinkModel", "LPFMachine", "probe",
+    "HardwareModel", "LinkModel", "LPFMachine", "hardware_for", "probe",
     "TPU_V5E", "TPU_V5P", "CPU_HOST",
     "Slot", "SlotRegistry", "Msg",
     "PlanCache", "CacheStats", "RoundPlan", "SuperstepPlan",

@@ -1,25 +1,20 @@
-"""JAX version-compatibility shims.
+"""JAX API shims: the one place that spells the version-sensitive symbols.
 
-The library targets every JAX from 0.4.35 (the oldest with
-``jax.make_mesh``) through 0.5+/0.6+.  A handful of symbols moved or
-changed signature across that range; every use of them in this repo MUST
-go through this module so there is exactly one place that knows the
-version story:
+The library targets the installed JAX (0.9).  A handful of symbols have
+moved or changed signature across releases; every use of them in this
+repo MUST go through this module so that a future move lands in one
+place:
 
-* ``shard_map`` — top-level ``jax.shard_map`` exists only on 0.6+; on
-  0.4.x it lives in ``jax.experimental.shard_map`` and spells the
-  replication check ``check_rep`` (new: ``check_vma``) and the partial
-  manualness set ``auto`` (new: ``axis_names``, the complement).
-* ``make_mesh`` — the ``axis_types`` kwarg (and ``jax.sharding.AxisType``
-  itself) only exists on 0.5+; older meshes are implicitly "auto".
-* ``tree_map`` & friends — ``jax.tree`` appeared in 0.4.25, before the
-  oldest release this repo supports, so these aliases exist only as a
-  convenience / insurance for even older jaxes; unlike ``shard_map``
-  and the mesh helpers above, calling ``jax.tree.*`` directly elsewhere
-  in the tree is fine.
+* ``shard_map`` — ``jax.shard_map`` with ``check_vma`` and the
+  ``axis_names`` set of manual axes.
+* ``make_mesh`` — every axis explicitly ``jax.sharding.AxisType.Auto``.
+* ``set_mesh`` — ``jax.set_mesh``.
+* ``enable_x64`` — ``jax.enable_x64(True)`` as a context manager (the
+  ``jax.experimental.enable_x64`` spelling is gone).
+* ``tree_map`` & friends — aliases of ``jax.tree.*``; calling
+  ``jax.tree.*`` directly elsewhere in the tree is fine.
 
-Nothing here imports anything heavier than ``jax`` itself, and all the
-probes are feature checks (``hasattr``), never version-string parses.
+Nothing here imports anything heavier than ``jax`` itself.
 """
 
 from __future__ import annotations
@@ -29,132 +24,66 @@ from typing import Any, Callable, Optional, Sequence, Set
 import jax
 
 __all__ = [
-    "HAS_AXIS_TYPE", "axis_types_auto", "make_mesh", "set_mesh",
+    "make_mesh", "set_mesh", "enable_x64",
     "shard_map", "scan", "while_loop", "tree_map", "tree_flatten",
     "tree_unflatten", "tree_leaves", "tree_structure",
 ]
 
-# -- axis types ------------------------------------------------------------
-
-#: True when this JAX has ``jax.sharding.AxisType`` (0.5+).
-HAS_AXIS_TYPE: bool = hasattr(jax.sharding, "AxisType")
-
-
-def axis_types_auto(n: int):
-    """``(AxisType.Auto,) * n`` on JAX 0.5+, else ``None`` (old meshes are
-    implicitly auto; ``Mesh``/``make_mesh`` take no such argument)."""
-    if HAS_AXIS_TYPE:
-        return (jax.sharding.AxisType.Auto,) * n
-    return None
-
-
-# -- mesh construction -----------------------------------------------------
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
               devices=None) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` with every axis explicitly ``Auto`` where the
-    concept exists, and plain construction where it does not."""
+    """``jax.make_mesh`` with every axis explicitly ``Auto``."""
     kwargs = {"devices": devices} if devices is not None else {}
-    types = axis_types_auto(len(tuple(axis_names)))
-    if types is not None:
-        return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
-                             axis_types=types, **kwargs)
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=(jax.sharding.AxisType.Auto,)
+                         * len(tuple(axis_names)),
+                         **kwargs)
 
 
 def set_mesh(mesh: jax.sharding.Mesh):
-    """Context manager activating ``mesh``: ``jax.set_mesh`` on 0.6+;
-    older ``Mesh`` objects are themselves context managers."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+    """Context manager activating ``mesh``."""
+    return jax.set_mesh(mesh)
 
 
-# -- shard_map -------------------------------------------------------------
+def enable_x64():
+    """Context manager enabling 64-bit types for the enclosed block."""
+    return jax.enable_x64(True)
+
 
 def shard_map(f: Callable, *, mesh: Any, in_specs: Any, out_specs: Any,
               check_vma: bool = False,
               axis_names: Optional[Set[str]] = None) -> Callable:
-    """Uniform ``shard_map`` over the old and new APIs.
-
-    ``axis_names`` follows the NEW convention: the set of mesh axes the
-    region is manual over (``None`` = all of them).  On 0.4.x this is
-    translated to the old ``auto=`` complement set, and ``check_vma``
-    becomes ``check_rep``.
-    """
-    if hasattr(jax, "shard_map"):
-        kwargs = {}
-        if axis_names is not None:
-            kwargs["axis_names"] = set(axis_names)
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma,
-                             **kwargs)
-    from jax.experimental.shard_map import shard_map as _shard_map
+    """``jax.shard_map``; ``axis_names`` is the set of mesh axes the
+    region is manual over (``None`` = all of them)."""
     kwargs = {}
     if axis_names is not None:
-        auto = frozenset(mesh.axis_names) - set(axis_names)
-        # Partial-manual lowering is unreliable on 0.4.x XLA (PartitionId
-        # is UNIMPLEMENTED under SPMD partitioning; sharding propagation
-        # CHECK-fails on IsManualSubgroup).  When no in/out spec touches
-        # an auto axis the region is semantically identical to a fully
-        # manual one — every device along the auto axes holds replicated
-        # data and runs the same program — so fall back to full manual.
-        if auto and not _specs_touch_axes((in_specs, out_specs), auto):
-            auto = frozenset()
-        if auto:
-            kwargs["auto"] = auto
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma, **kwargs)
-
-
-def _specs_touch_axes(specs, axes: frozenset) -> bool:
-    """True if any PartitionSpec leaf in ``specs`` names one of ``axes``."""
-    P = jax.sharding.PartitionSpec
-    hit = False
-    for leaf in jax.tree_util.tree_leaves(
-            specs, is_leaf=lambda x: isinstance(x, P)):
-        if not isinstance(leaf, P):
-            continue
-        for entry in leaf:
-            names = entry if isinstance(entry, tuple) else (entry,)
-            if any(n in axes for n in names if n is not None):
-                hit = True
-    return hit
+        kwargs["axis_names"] = set(axis_names)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma, **kwargs)
 
 
 # -- structured control flow -----------------------------------------------
 #
-# ``lax.scan``/``lax.while_loop`` are stable across the supported range,
-# but they are the symbols whole-program compilation (compiled
-# SuperstepProgram replay, ``LPFContext.compile_loop``, the fused decode
-# loop) hangs off — routed through here like every other symbol the
-# version story could ever touch, so a future signature change has one
-# place to land.
+# ``lax.scan``/``lax.while_loop`` are the symbols whole-program
+# compilation (compiled SuperstepProgram replay, ``LPFContext
+# .compile_loop``, the fused decode loop) hangs off — routed through here
+# so a future signature change has one place to land.
 
 def scan(f, init, xs, length=None):
     """``lax.scan`` (body traced once; per-iteration work compiles into
     ONE XLA ``While`` op instead of a Python-dispatched call per step)."""
-    import jax.lax
     return jax.lax.scan(f, init, xs, length=length)
 
 
 def while_loop(cond_fun, body_fun, init_val):
     """``lax.while_loop`` — same single-trace contract as :func:`scan`."""
-    import jax.lax
     return jax.lax.while_loop(cond_fun, body_fun, init_val)
 
 
 # -- pytree helpers --------------------------------------------------------
 
-if hasattr(jax, "tree") and hasattr(jax.tree, "map"):
-    tree_map = jax.tree.map
-    tree_flatten = jax.tree.flatten
-    tree_unflatten = jax.tree.unflatten
-    tree_leaves = jax.tree.leaves
-    tree_structure = jax.tree.structure
-else:  # pragma: no cover - ancient JAX
-    tree_map = jax.tree_util.tree_map
-    tree_flatten = jax.tree_util.tree_flatten
-    tree_unflatten = jax.tree_util.tree_unflatten
-    tree_leaves = jax.tree_util.tree_leaves
-    tree_structure = jax.tree_util.tree_structure
+tree_map = jax.tree.map
+tree_flatten = jax.tree.flatten
+tree_unflatten = jax.tree.unflatten
+tree_leaves = jax.tree.leaves
+tree_structure = jax.tree.structure

@@ -23,6 +23,7 @@ __all__ = [
     "TPU_V5E",
     "TPU_V5P",
     "CPU_HOST",
+    "hardware_for",
     "probe",
     "axis_kind_default",
 ]
@@ -97,6 +98,26 @@ CPU_HOST = HardwareModel(
         "dcn": LinkModel(bw=1e9, latency=1e-4),
     },
 )
+
+
+#: ``jax.Device.device_kind`` -> hardware model.  A device kind missing
+#: here has no known constants, and :func:`hardware_for` refuses it.
+_BY_DEVICE_KIND: Mapping[str, HardwareModel] = {
+    "TPU v5 lite": TPU_V5E,
+    "TPU v5e": TPU_V5E,
+    "cpu": CPU_HOST,
+}
+
+
+def hardware_for(device_kind: str) -> HardwareModel:
+    """The :class:`HardwareModel` of a device, by its ``device_kind``.
+    Raises ``KeyError`` for a kind this table does not know."""
+    try:
+        return _BY_DEVICE_KIND[device_kind]
+    except KeyError:
+        raise KeyError(f"no hardware model for device kind "
+                       f"{device_kind!r}; known: "
+                       f"{sorted(_BY_DEVICE_KIND)}") from None
 
 
 @dataclasses.dataclass(frozen=True)

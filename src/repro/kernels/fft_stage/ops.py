@@ -2,8 +2,6 @@
 
 from __future__ import annotations
 
-from typing import Optional
-
 import jax
 import jax.numpy as jnp
 
@@ -12,13 +10,10 @@ from . import kernel as _k
 __all__ = ["fft", "ifft"]
 
 
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
-def _run(x: jnp.ndarray, inverse: bool, interpret: Optional[bool]):
-    if interpret is None:
-        interpret = _default_interpret()
+def _run(x: jnp.ndarray, inverse: bool, interpret: bool):
+    x = jnp.asarray(x)
+    if not jnp.issubdtype(x.dtype, jnp.complexfloating):
+        x = x.astype(jnp.complex64)
     shape = x.shape
     n = shape[-1]
     xr = jnp.real(x).astype(jnp.float32).reshape(-1, n)
@@ -28,20 +23,11 @@ def _run(x: jnp.ndarray, inverse: bool, interpret: Optional[bool]):
         jnp.complex64 if x.dtype != jnp.complex128 else x.dtype)
 
 
-def fft(x: jnp.ndarray, *, interpret: Optional[bool] = None) -> jnp.ndarray:
+def fft(x: jnp.ndarray, *, interpret: bool = False) -> jnp.ndarray:
     """FFT along the last axis (power-of-two length)."""
-    x = jnp.asarray(x)
-    if not jnp.issubdtype(x.dtype, jnp.complexfloating):
-        x = x.astype(jnp.complex64)
-    if x.ndim == 1:
-        return _run(x[None], False, interpret)[0]
     return _run(x, False, interpret)
 
 
-def ifft(x: jnp.ndarray, *, interpret: Optional[bool] = None) -> jnp.ndarray:
-    x = jnp.asarray(x)
-    if not jnp.issubdtype(x.dtype, jnp.complexfloating):
-        x = x.astype(jnp.complex64)
-    if x.ndim == 1:
-        return _run(x[None], True, interpret)[0]
+def ifft(x: jnp.ndarray, *, interpret: bool = False) -> jnp.ndarray:
+    """Inverse FFT along the last axis (power-of-two length)."""
     return _run(x, True, interpret)

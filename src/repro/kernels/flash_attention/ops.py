@@ -1,9 +1,9 @@
 """jit'd public wrapper for flash attention with a custom VJP.
 
-``flash_attention`` dispatches to the Pallas TPU kernel (or its
-``interpret=True`` execution on CPU) and differentiates through the
-hand-written backward kernels.  On non-TPU backends ``interpret`` defaults
-to True so the same call validates everywhere.
+``flash_attention`` runs the Pallas TPU kernel and differentiates
+through the hand-written backward kernels.  ``interpret=True`` runs the
+same kernels in the Pallas interpreter (CPU tests); it is never chosen
+implicitly.
 """
 
 from __future__ import annotations
@@ -17,10 +17,6 @@ import jax.numpy as jnp
 from . import kernel as _k
 
 __all__ = ["flash_attention"]
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.custom_vjp,
@@ -58,9 +54,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                     softcap: Optional[float] = None,
                     scale: Optional[float] = None,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: Optional[bool] = None) -> jnp.ndarray:
+                    interpret: bool = False) -> jnp.ndarray:
     """Flash attention: q [B,H,S,D], k/v [B,Hkv,S,D] -> [B,H,S,D]."""
-    if interpret is None:
-        interpret = _default_interpret()
     return _flash(q, k, v, causal, window, softcap, scale,
                   block_q, block_k, interpret)

@@ -7,9 +7,9 @@ is carried.  The chunk dimension is the grid's minor-most axis, so the
 running state lives in VMEM scratch and flows sequentially — the same
 accumulation idiom as the flash-attention kernels.
 
-Per chunk (head h, all f32):
+Per chunk (head h, all f32; per-position vectors are [L, 1] columns):
     dA   = dt * A_h                       [L]
-    cum  = cumsum(dA)                     [L]
+    cum  = cumsum(dA) = tril(1) @ dA      [L]
     Yin  = ((C B^T) o exp(cum_i - cum_j) o (i>=j) o dt_j) x     (intra)
     Yout = (C o exp(cum)_i) state_prev                          (inter)
     state = exp(cum_L) state_prev + (B o (exp(cum_L - cum) dt))^T x
@@ -25,8 +25,17 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_ref,
-                s_scr, *, L: int, P: int, N: int):
+_HI = jax.lax.Precision.HIGHEST
+
+
+def _dot(a, b, contract=((1,), (0,))):
+    return jax.lax.dot_general(a, b, (contract, ((), ())), precision=_HI,
+                               preferred_element_type=jnp.float32)
+
+
+def _ssd_kernel(a_ref, x_ref, dt_ref, b_ref, c_ref, y_ref, state_ref,
+                s_scr, *, L: int):
+    h = pl.program_id(1)
     c_idx = pl.program_id(2)      # chunk (sequential)
     nc = pl.num_programs(2)
 
@@ -35,35 +44,34 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_ref,
         s_scr[...] = jnp.zeros_like(s_scr)
 
     x = x_ref[0, 0].astype(jnp.float32)          # [L, P]
-    dt = dt_ref[0, 0].astype(jnp.float32)        # [L, 1]  (lane-padded)
-    a = a_ref[0, 0].astype(jnp.float32)          # [1, 1] scalar A_h
+    dt = dt_ref[0, 0].astype(jnp.float32)        # [L, 1]
     bmat = b_ref[0, 0].astype(jnp.float32)       # [L, N]
     cmat = c_ref[0, 0].astype(jnp.float32)       # [L, N]
+    dA = dt * a_ref[h]                           # [L, 1]
 
-    dA = dt[:, 0] * a[0, 0]                      # [L]
-    cum = jnp.cumsum(dA)                         # [L]
-
-    # intra-chunk quadratic form
-    cb = jax.lax.dot_general(cmat, bmat, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)  # [L, L]
-    seg = cum[:, None] - cum[None, :]
+    # every per-position quantity stays a 2-D [L, 1] column; the
+    # cumulative sum is a lower-triangular matmul, broadcast over lanes:
+    # cum_b[i, j] = cum_i, so cum_b - cum_b.T is the segment sum i..j
     causal = (jax.lax.broadcasted_iota(jnp.int32, (L, L), 0)
               >= jax.lax.broadcasted_iota(jnp.int32, (L, L), 1))
-    seg = jnp.where(causal, seg, -1e30)   # pre-exp clamp (no inf leakage)
-    m = cb * jnp.exp(seg) * dt[:, 0][None, :]
-    y = jax.lax.dot(m, x, preferred_element_type=jnp.float32)     # [L, P]
+    cum_b = _dot(causal.astype(jnp.float32), jnp.broadcast_to(dA, (L, L)))
+    cum = cum_b[:, :1]                           # [L, 1]
+    total = jnp.sum(dA)                          # scalar cum_L
+    seg = jnp.where(causal, cum_b - cum_b.T, -1e30)  # pre-exp clamp
+    dt_row = jnp.broadcast_to(dt, (L, L)).T      # [i, j] = dt_j
+
+    # intra-chunk quadratic form
+    cb = _dot(cmat, bmat, ((1,), (1,)))                           # [L, L]
+    y = _dot(cb * jnp.exp(seg) * dt_row, x)                       # [L, P]
 
     # inter-chunk contribution from the running state  [N, P]
     state = s_scr[...]
-    y += jax.lax.dot(cmat * jnp.exp(cum)[:, None], state,
-                     preferred_element_type=jnp.float32)
+    y += _dot(cmat * jnp.exp(cum), state)
 
     # state update
-    decay_end = jnp.exp(cum[L - 1] - cum) * dt[:, 0]              # [L]
-    s_new = (jnp.exp(cum[L - 1]) * state
-             + jax.lax.dot_general(bmat * decay_end[:, None], x,
-                                   (((0,), (0,)), ((), ())),
-                                   preferred_element_type=jnp.float32))
+    decay_end = jnp.exp(total - cum) * dt                         # [L, 1]
+    s_new = jnp.exp(total) * state + _dot(bmat * decay_end, x,
+                                          ((0,), (0,)))
     s_scr[...] = s_new
     y_ref[0, 0] = y.astype(y_ref.dtype)
 
@@ -95,16 +103,15 @@ def ssd_scan(x: jnp.ndarray, dt: jnp.ndarray, a: jnp.ndarray,
     dtt = jnp.swapaxes(dt, 1, 2)[..., None]           # [B, H, S, 1]
     bt = jnp.swapaxes(b, 1, 2)                        # [B, G, S, N]
     ct = jnp.swapaxes(c, 1, 2)
-    a2 = a.reshape(H, 1, 1).astype(jnp.float32)       # [H, 1, 1]
 
-    kernel = functools.partial(_ssd_kernel, L=L, P=P, N=N)
+    kernel = functools.partial(_ssd_kernel, L=L)
     y, state = pl.pallas_call(
         kernel,
         grid=(B, H, nc),
         in_specs=[
+            pl.BlockSpec(memory_space=pltpu.SMEM),    # a [H] scalars
             pl.BlockSpec((1, 1, L, P), lambda bb, h, cc: (bb, h, cc, 0)),
             pl.BlockSpec((1, 1, L, 1), lambda bb, h, cc: (bb, h, cc, 0)),
-            pl.BlockSpec((1, 1, 1, 1), lambda bb, h, cc: (0, h, 0, 0)),
             pl.BlockSpec((1, 1, L, N),
                          lambda bb, h, cc, g=hg: (bb, h // g, cc, 0)),
             pl.BlockSpec((1, 1, L, N),
@@ -120,5 +127,5 @@ def ssd_scan(x: jnp.ndarray, dt: jnp.ndarray, a: jnp.ndarray,
         ],
         scratch_shapes=[pltpu.VMEM((N, P), jnp.float32)],
         interpret=interpret,
-    )(xt, dtt, a2[None], bt, ct)
+    )(a.astype(jnp.float32), xt, dtt, bt, ct)
     return jnp.swapaxes(y, 1, 2), state

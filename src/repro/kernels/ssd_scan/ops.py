@@ -1,11 +1,11 @@
-"""Public wrapper for the SSD scan: Pallas on TPU, interpret elsewhere;
-reference VJP (the recurrence differentiates cleanly through the oracle
-while the kernel serves the forward hot path)."""
+"""Public wrapper for the SSD scan: the Pallas kernel forward
+(``interpret=True`` runs it in the Pallas interpreter, for CPU tests)
+and a reference VJP (the recurrence differentiates cleanly through the
+oracle while the kernel serves the forward hot path)."""
 
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -14,10 +14,6 @@ from . import kernel as _k
 from . import ref as _ref
 
 __all__ = ["ssd"]
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
@@ -42,8 +38,6 @@ _ssd.defvjp(_ssd_fwd, _ssd_bwd)
 
 def ssd(x: jnp.ndarray, dt: jnp.ndarray, a: jnp.ndarray, b: jnp.ndarray,
         c: jnp.ndarray, *, chunk: int = 128,
-        interpret: Optional[bool] = None) -> jnp.ndarray:
+        interpret: bool = False) -> jnp.ndarray:
     """SSD scan output y [B, S, H, P] (see kernel.ssd_scan)."""
-    if interpret is None:
-        interpret = _default_interpret()
     return _ssd(x, dt, a, b, c, chunk, interpret)

@@ -1,5 +1,9 @@
 import os
+# the dry-run compiles for 512 emulated CPU devices and never touches an
+# accelerator, so neither this process nor the ``--all`` children it
+# starts ever holds a chip
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 

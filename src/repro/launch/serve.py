@@ -23,6 +23,7 @@ completed request solo and verifies exactly that.
 from __future__ import annotations
 
 import argparse
+import functools
 import os
 import time
 from typing import Dict, Sequence, Tuple
@@ -48,10 +49,19 @@ class ModelDecodeEngine:
         self._cfg = cfg
         self._init_caches = init_caches
         self._steps = build_serve_buckets(cfg, mesh, buckets)
-        self._params = {
-            b: jax.device_put(init_params(jax.random.PRNGKey(0), cfg),
-                              ss.param_sharding)
-            for b, ss in self._steps.items()}
+        # one device copy of the weights per distinct param sharding:
+        # buckets differ in batch and cache length, not in weights
+        self._params = {}
+        copies = []                      # [(sharding leaves, params)]
+        for b, ss in self._steps.items():
+            leaves = jax.tree.leaves(ss.param_sharding)
+            params = next((p for l, p in copies if l == leaves), None)
+            if params is None:
+                params = jax.jit(functools.partial(init_params, cfg=cfg),
+                                 out_shardings=ss.param_sharding)(
+                    jax.random.PRNGKey(0))
+                copies.append((leaves, params))
+            self._params[b] = params
         self._enc = {}
         for b, ss in self._steps.items():
             self._enc[b] = (jnp.zeros((b[0], 64, cfg.d_model),
@@ -137,6 +147,21 @@ class ModelDecodeEngine:
             self._overhead_s[b] = max((t1 - t0) - per_tok, 0.0)
 
 
+def solo_mismatches(eng, reqs, done) -> list:
+    """Re-decode every completed request alone in its bucket; returns
+    the rids whose batched stream differs from the solo decode (greedy
+    decode is row-independent, so the list must be empty)."""
+    by_rid = {r.rid: r for r in reqs}
+    bad = []
+    for o in sorted(done, key=lambda o: o.rid):
+        r = by_rid[o.rid]
+        solo = eng.decode(o.bucket, [r],
+                          eng.round_tokens(o.bucket, r.n_tokens))
+        if tuple(solo[r.rid][:r.n_tokens]) != tuple(o.tokens):
+            bad.append(o.rid)
+    return bad
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-1b")
@@ -171,8 +196,11 @@ def main():
             f"--xla_force_host_platform_device_count={args.devices}")
 
     from repro.configs import get_config
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import make_mesh
     from repro.runtime.server import LPFServer, synthetic_requests
+
+    enable_compile_cache()
 
     mesh = make_mesh(tuple(int(x) for x in args.mesh.split("x")))
     cfg = get_config(args.arch, smoke=args.smoke,
@@ -234,16 +262,11 @@ def main():
         raise SystemExit(f"unclassified refusals: rids {unclassified}")
 
     if args.check:
-        bad = 0
-        for o in sorted(done, key=lambda o: o.rid):
-            r = next(r for r in reqs if r.rid == o.rid)
-            solo = eng.decode(o.bucket, [r],
-                              eng.round_tokens(o.bucket, r.n_tokens))
-            if tuple(solo[r.rid][:r.n_tokens]) != tuple(o.tokens):
-                bad += 1
-                print(f"  CHECK FAILED rid {o.rid}: batched stream "
-                      f"differs from solo decode")
-        print(f"check: {len(done) - bad}/{len(done)} completed "
+        bad = solo_mismatches(eng, reqs, done)
+        for rid in bad:
+            print(f"  CHECK FAILED rid {rid}: batched stream "
+                  f"differs from solo decode")
+        print(f"check: {len(done) - len(bad)}/{len(done)} completed "
               f"requests bit-identical to solo decode")
         if bad:
             raise SystemExit(1)

@@ -42,15 +42,16 @@ def main():
         import jax
         jax.distributed.initialize()
 
-    import jax
     from repro.configs import get_config
     from repro.core import CompressSpec, SyncAttributes
     from repro.data import DataConfig, SyntheticStream
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import make_mesh
     from repro.optim import AdamWConfig, warmup_cosine
     from repro.runtime.train_loop import TrainLoopConfig, train_loop
     from repro.runtime.train_step import build_train_step
 
+    enable_compile_cache()
     shape = tuple(int(x) for x in args.mesh.split("x"))
     mesh = make_mesh(shape)
     cfg = get_config(args.arch, smoke=args.smoke,

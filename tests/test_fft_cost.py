@@ -13,10 +13,10 @@ runs on.
 import numpy as np
 import pytest
 
-import jax
 import jax.numpy as jnp
 
 from repro.algorithms import bsp_fft
+from repro.core import compat
 from repro.algorithms.fft import fft_h_bytes
 
 pytestmark = pytest.mark.slow
@@ -50,7 +50,7 @@ def test_fft_ledger_matches_h_bytes_complex64(mesh8, ordered):
 @pytest.mark.parametrize("ordered", [True, False])
 def test_fft_ledger_matches_h_bytes_complex128(mesh8, ordered):
     n, p = 1024, 8
-    with jax.experimental.enable_x64():
+    with compat.enable_x64():
         ledger, rel = _run(mesh8, n, np.complex128, ordered)
     assert rel < 1e-10
     assert ledger.supersteps == (2 if ordered else 1)

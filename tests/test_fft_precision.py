@@ -10,10 +10,10 @@ from ``test_immortal_algorithms.py`` so it runs without hypothesis.)
 import numpy as np
 import pytest
 
-import jax
 import jax.numpy as jnp
 
 from repro.algorithms import bsp_fft
+from repro.core import compat
 
 pytestmark = pytest.mark.slow
 
@@ -22,7 +22,7 @@ def test_fft_complex128_twiddle_precision(mesh8):
     """n = 2**16 complex128 FFT must reach float64-grade accuracy; the
     float32-phase bug sat at ~1e-3 relative error on this input."""
     n = 1 << 16
-    with jax.experimental.enable_x64():
+    with compat.enable_x64():
         rng = np.random.default_rng(0)
         x = (rng.standard_normal(n) + 1j * rng.standard_normal(n)
              ).astype(np.complex128)

@@ -126,3 +126,57 @@ def test_partition_roundtrip_spmv(mesh8, rng):
                     out_specs=P("x"))
     np.testing.assert_allclose(np.asarray(out).reshape(-1), want,
                                rtol=1e-5, atol=1e-6)
+
+
+def _rmat_loop(n, m, seed, a=0.57, b=0.19, c=0.19):
+    """The per-edge loop generator ``rmat_graph`` vectorizes: the first
+    ``m`` distinct non-loop edges of the same seeded draw stream."""
+    rng = np.random.default_rng(seed)
+    scale = int(np.log2(n))
+    probs = np.array([a, b, c, 1.0 - a - b - c])
+    weights = 1 << np.arange(scale - 1, -1, -1, dtype=np.int64)
+    edges = set()
+    while len(edges) < m:
+        quad = rng.choice(4, size=(max(4 * m, 1024), scale), p=probs)
+        src = (quad >= 2).astype(np.int64) @ weights
+        dst = (quad % 2).astype(np.int64) @ weights
+        for s, d in zip(src, dst):
+            if s != d:
+                edges.add((int(s), int(d)))
+                if len(edges) >= m:
+                    break
+    return np.array(sorted(edges), dtype=np.int64)
+
+
+@pytest.mark.parametrize("n,m,seed", [(64, 200, 5), (64, 1000, 2),
+                                      (128, 400, 3), (1024, 6144, 1)])
+def test_rmat_matches_loop_reference(n, m, seed):
+    np.testing.assert_array_equal(rmat_graph(n, m, seed=seed),
+                                  _rmat_loop(n, m, seed))
+
+
+def _dense_pagerank(edges, n, alpha=0.85, tol=1e-10, max_iter=500):
+    A = np.zeros((n, n), np.float64)
+    outdeg = np.bincount(edges[:, 0], minlength=n)
+    for s, d in edges:
+        A[d, s] = 1.0 / outdeg[s]
+    dangling = (outdeg == 0).astype(np.float64)
+    r = np.full(n, 1.0 / n)
+    for it in range(max_iter):
+        r_new = alpha * (A @ r + np.dot(dangling, r) / n) + (1 - alpha) / n
+        if np.abs(r_new - r).sum() < tol:
+            return r_new, it + 1
+        r = r_new
+    return r, max_iter
+
+
+@pytest.mark.parametrize("edges,n", [(banded_graph(64, 3), 64),
+                                     (rmat_graph(128, 400, seed=3), 128),
+                                     (rmat_graph(512, 2048, seed=7), 512)])
+def test_reference_pagerank_matches_dense(edges, n):
+    """The sparse oracle equals the dense A @ r iteration (same math,
+    another summation order: float64 round-off only)."""
+    r, iters = reference_pagerank(edges, n)
+    r_dense, iters_dense = _dense_pagerank(edges, n)
+    assert iters == iters_dense
+    np.testing.assert_allclose(r, r_dense, rtol=1e-12, atol=0)

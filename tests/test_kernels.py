@@ -1,4 +1,8 @@
-"""Pallas kernels vs pure-jnp oracles (interpret=True shape/dtype sweeps)."""
+"""Pallas kernels vs pure-jnp oracles (interpret=True shape/dtype sweeps).
+
+``interpret=True`` runs each kernel in the Pallas interpreter on the
+CPU; ``tests/test_chip_compile.py`` checks that the same kernels compile
+for the chip."""
 
 import jax
 import jax.numpy as jnp

@@ -257,6 +257,20 @@ def test_probe_table():
     assert m2.g > m.g * 0.9   # DCN-dominated g is never better than ICI
 
 
+@pytest.mark.parametrize("kind,hw", [("TPU v5 lite", "TPU_V5E"),
+                                     ("TPU v5e", "TPU_V5E"),
+                                     ("cpu", "CPU_HOST")])
+def test_hardware_for_known_device_kinds(kind, hw):
+    assert lpf.hardware_for(kind) is getattr(lpf, hw)
+
+
+def test_hardware_for_unknown_device_kind_is_an_error():
+    with pytest.raises(KeyError, match="TPU v4"):
+        lpf.hardware_for("TPU v4")
+    # the device this test runs on is one the table knows
+    lpf.hardware_for(jax.devices()[0].device_kind)
+
+
 def test_ledger_h_relation(mesh8):
     """The ledger must record exactly the BSP h-relation of the pattern."""
     def spmd(ctx, s, p, _):

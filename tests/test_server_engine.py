@@ -72,3 +72,21 @@ def test_serve_chaos_invariant_smoke():
         verdict, detail = _run_one("serve", FaultPlan.parse(spec),
                                    baselines)
         assert verdict in ("identical", "classified"), (spec, detail)
+
+
+def test_model_engine_shares_weights_across_buckets():
+    """Buckets differ in batch and cache length, not in weights: the
+    model engine holds one device copy of the params for all buckets
+    with the same param sharding, and a request decodes the same stream
+    solo and batched."""
+    from repro.configs import get_config
+    from repro.launch.mesh import make_mesh
+    from repro.launch.serve import ModelDecodeEngine
+
+    eng = ModelDecodeEngine(get_config("llama3.2-1b", smoke=True),
+                            make_mesh((1, 1)), [(2, 16), (4, 16)],
+                            calibrate_tokens=2)
+    assert eng._params[(2, 16)] is eng._params[(4, 16)]
+    a, b = req(0, seed=1234), req(1, seed=777)
+    assert eng.decode((4, 16), [a], 4)[0] == \
+        eng.decode((4, 16), [a, b], 4)[0] == eng.decode((2, 16), [a], 4)[0]
