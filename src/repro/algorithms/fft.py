@@ -40,7 +40,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import LPFContext, LPF_SYNC_DEFAULT, SyncAttributes, exec_, hook
+from repro.core import (LPFContext, LPF_SYNC_DEFAULT, SyncAttributes, exec_,
+                        hook, span)
 from jax.sharding import PartitionSpec as P
 
 __all__ = ["bsp_fft_spmd", "bsp_fft", "fft_flops", "fft_h_bytes"]
@@ -173,7 +174,8 @@ def bsp_fft(mesh: jax.sharding.Mesh, x: jnp.ndarray, *,
     n = int(x.shape[0])
     # cyclic layout, pid-major; strided slices, because an [n/p, p]
     # view would pad its p-wide minor dimension to a full TPU lane tile
-    xc = jnp.concatenate([x[s::p] for s in range(p)])
+    with span("lpf.fft.layout"):
+        xc = jnp.concatenate([x[s::p] for s in range(p)])
 
     def spmd(ctx, s, pp, xt):
         xl = xt.reshape(pp, n // pp)[s]

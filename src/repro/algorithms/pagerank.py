@@ -33,7 +33,8 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro import bsp
-from repro.core import LPFContext, LPF_SYNC_DEFAULT, SyncAttributes, exec_
+from repro.core import (LPFContext, LPF_SYNC_DEFAULT, SyncAttributes, exec_,
+                        span)
 from .graphs import PartitionedGraph
 
 __all__ = ["lpf_pagerank", "pagerank_spmd", "dataflow_pagerank",
@@ -142,11 +143,13 @@ def lpf_pagerank(mesh: jax.sharding.Mesh, g: PartitionedGraph, *,
                  attrs: SyncAttributes = LPF_SYNC_DEFAULT):
     """Whole-graph driver: distribute shards, run, gather [n] ranks."""
     axes = tuple(axes) if axes is not None else tuple(mesh.axis_names)
-    args = {
-        "row_ids": jnp.asarray(g.row_ids), "col_ext": jnp.asarray(g.col_ext),
-        "vals": jnp.asarray(g.vals), "pack_idx": jnp.asarray(g.pack_idx),
-        "dangling": jnp.asarray(g.dangling),
-    }
+    with span("lpf.pagerank.upload"):
+        args = {
+            "row_ids": jnp.asarray(g.row_ids),
+            "col_ext": jnp.asarray(g.col_ext),
+            "vals": jnp.asarray(g.vals), "pack_idx": jnp.asarray(g.pack_idx),
+            "dangling": jnp.asarray(g.dangling),
+        }
     in_specs = {k: P(axes) for k in args}
 
     def spmd(ctx, s, p, a):

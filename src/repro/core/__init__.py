@@ -38,6 +38,7 @@ from .program import (CompiledProgram, OptimizedStep, ProgramCache,
                       compile_program, dependency_cone,
                       global_program_cache, optimize_program,
                       program_signature, simulate_program, trace_slot_map)
+from .spans import span
 from .sync import (CacheStats, Msg, OVERLAPPABLE_METHODS, PlanCache,
                    RoundPlan, SuperstepPlan, ValueStore, begin_plan,
                    conflict_free, execute_overlapped, execute_plan,
@@ -70,4 +71,5 @@ __all__ = [
     "simulate_program", "ValueStore", "execute_schedule",
     "PersistentStore", "PersistError", "steps_from_signature",
     "CollectiveStats", "RooflineTerms", "parse_collectives", "roofline_terms",
+    "span",
 ]

@@ -11,6 +11,7 @@ place:
 * ``set_mesh`` — ``jax.set_mesh``.
 * ``enable_x64`` — ``jax.enable_x64(True)`` as a context manager (the
   ``jax.experimental.enable_x64`` spelling is gone).
+* ``tracing`` — whether a transformation is tracing the caller.
 * ``tree_map`` & friends — aliases of ``jax.tree.*``; calling
   ``jax.tree.*`` directly elsewhere in the tree is fine.
 
@@ -24,7 +25,7 @@ from typing import Any, Callable, Optional, Sequence, Set
 import jax
 
 __all__ = [
-    "make_mesh", "set_mesh", "enable_x64",
+    "make_mesh", "set_mesh", "enable_x64", "tracing",
     "shard_map", "scan", "while_loop", "tree_map", "tree_flatten",
     "tree_unflatten", "tree_leaves", "tree_structure",
 ]
@@ -48,6 +49,12 @@ def set_mesh(mesh: jax.sharding.Mesh):
 def enable_x64():
     """Context manager enabling 64-bit types for the enclosed block."""
     return jax.enable_x64(True)
+
+
+def tracing() -> bool:
+    """Whether a JAX transformation (an enclosing ``jit``, ``grad``,
+    ``vmap``) is tracing the caller."""
+    return not jax.core.trace_ctx.is_top_level()
 
 
 def shard_map(f: Callable, *, mesh: Any, in_specs: Any, out_specs: Any,

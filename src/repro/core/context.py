@@ -40,6 +40,7 @@ from .memslot import Slot, SlotRegistry
 from .program import (ProgramCache, ProgramStep, compile_program,
                       dependency_cone, global_program_cache,
                       trace_slot_map)
+from .spans import span
 from .sync import (Msg, PlanCache, execute_plan, execute_schedule,
                    global_plan_cache)
 
@@ -366,6 +367,11 @@ class LPFContext:
         optimization (coalescing, dead-transfer elimination, batching,
         split-phase overlap).  In that case ``sync`` returns ``None``
         and the ledger entries appear at flush time."""
+        with span("lpf.sync"):
+            return self._sync(attrs, label)
+
+    def _sync(self, attrs: SyncAttributes,
+              label: str) -> Optional[SuperstepCost]:
         self._require_active()
         if not label:
             prefix = next((l for l in reversed(self._rec_labels) if l), "")
@@ -599,7 +605,8 @@ class LPFContext:
             return
         steps, self._rec_pending = self._rec_pending, []
         self._rec_marks = [0] * len(self._rec_marks)
-        self._execute_steps(steps)
+        with span("lpf.flush"):
+            self._execute_steps(steps)
         self._drain_deferred_dereg()
 
     def _flush_cone(self, slot: Slot, include_reads: bool) -> None:
@@ -626,7 +633,8 @@ class LPFContext:
         # just flushed no longer occupy pending positions
         self._rec_marks = [m - sum(1 for i in cone_set if i < m)
                            for m in self._rec_marks]
-        self._execute_steps(steps)
+        with span("lpf.flush"):
+            self._execute_steps(steps)
         self._drain_deferred_dereg()
 
     # ------------------------------------------------------------------
@@ -797,7 +805,12 @@ def exec_(mesh: jax.sharding.Mesh, spmd: Callable, args: Any = None, *,
     expected replicated (``out_specs=P()``), mirroring the C API's
     broadcast args; pass explicit specs for distributed I/O.  With
     ``return_ledger=True`` also returns the cost ledger recorded at trace
-    time, for compliance checking."""
+    time, for compliance checking.
+
+    The call is staged (trace, lower, compile, run), each stage a span
+    of its own (:mod:`repro.core.spans`); nothing is kept across calls.
+    Inside a caller's trace (an enclosing ``jit``) it nests into that
+    trace, as :func:`hook` does, and has no stages of its own."""
     axes = tuple(axes) if axes is not None else tuple(mesh.axis_names)
     ledger_box: List[CostLedger] = []
 
@@ -810,9 +823,20 @@ def exec_(mesh: jax.sharding.Mesh, spmd: Callable, args: Any = None, *,
         in_specs = compat.tree_map(lambda _: P(), args)
     fn = compat.shard_map(wrapped, mesh=mesh, in_specs=(in_specs,),
                           out_specs=out_specs, check_vma=False)
-    if jit:
-        fn = jax.jit(fn)
-    out = fn(args)
+    if compat.tracing():
+        out = (jax.jit(fn) if jit else fn)(args)
+    elif jit:
+        with span("lpf.exec.trace"):
+            traced = jax.jit(fn).trace(args)
+        with span("lpf.exec.lower"):
+            lowered = traced.lower()
+        with span("lpf.exec.compile"):
+            compiled = lowered.compile()
+        with span("lpf.exec.run"):
+            out = compiled(args)
+    else:
+        with span("lpf.exec.run"):
+            out = fn(args)
     if return_ledger:
         return out, (ledger_box[0] if ledger_box else CostLedger())
     return out
