@@ -33,6 +33,7 @@ TPU-tiled) when ``use_kernel=True``, else ``jnp.fft.fft``.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Optional
 
@@ -69,6 +70,20 @@ def _local_fft(x: jnp.ndarray, use_kernel: bool) -> jnp.ndarray:
         from repro.kernels.fft_stage import ops as fft_ops
         return fft_ops.fft(x)
     return jnp.fft.fft(x)
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _strided_layout(x: jnp.ndarray, p: int) -> jnp.ndarray:
+    n = x.shape[0]
+    return jnp.concatenate([jax.lax.slice(x, (s,), (n,), (p,))
+                            for s in range(p)])
+
+
+def cyclic_layout(x, p: int):
+    """The cyclic layout of ``x`` over ``p`` processes, pid-major:
+    ``out[s*(n/p) + l] = x[l*p + s]``.  At ``p == 1`` it is ``x`` itself,
+    with no device op."""
+    return x if p == 1 else _strided_layout(x, p)
 
 
 def bsp_fft_spmd(ctx: LPFContext, x_local: jnp.ndarray, n: int, *,
@@ -172,10 +187,12 @@ def bsp_fft(mesh: jax.sharding.Mesh, x: jnp.ndarray, *,
     axes = tuple(axes) if axes is not None else tuple(mesh.axis_names)
     p = int(np.prod([mesh.shape[a] for a in axes]))
     n = int(x.shape[0])
-    # cyclic layout, pid-major; strided slices, because an [n/p, p]
-    # view would pad its p-wide minor dimension to a full TPU lane tile
+    # cyclic layout, pid-major, from strided slices (lax.slice with a
+    # stride): a step-indexed x[s::p] lowers to a gather, and an
+    # [n/p, p] view pads its p-wide minor dimension to a full TPU lane
+    # tile, a 32 GiB buffer for complex64 at n = 2^28
     with span("lpf.fft.layout"):
-        xc = jnp.concatenate([x[s::p] for s in range(p)])
+        xc = cyclic_layout(x, p)
 
     def spmd(ctx, s, pp, xt):
         xl = xt.reshape(pp, n // pp)[s]

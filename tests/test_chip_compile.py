@@ -4,7 +4,9 @@ Each test lowers and compiles one kernel ahead of time for one chip of a
 *described* ``v5e:2x2`` topology: the TPU compiler (Mosaic) runs here,
 with no chip attached, and refuses what the chip would refuse — shapes
 it cannot lay out, operations it cannot lower, more VMEM than a kernel
-may use.  The interpret-mode tests cannot see any of that.
+may use.  The interpret-mode tests cannot see any of that.  One more
+test compiles ``bsp_fft``'s cyclic layout, which is no kernel, to see
+that it runs as strided slices on the chip rather than as a gather.
 
 Widths are the ones ``chip_smoke.py`` runs: flash attention at
 llama3.2-1b (H=32, Hkv=8, S=2048, D=64, bf16), ``fft_stage`` on
@@ -18,12 +20,14 @@ loads it.
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.algorithms.fft import _strided_layout
 from repro.kernels.fft_stage import kernel as fft_kernel
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.ssd_scan.kernel import ssd_scan
@@ -95,3 +99,12 @@ def test_ssd_scan_compiles(one_chip):
              _sds(one_chip, (B, S, H, P)), _sds(one_chip, (B, S, H)),
              _sds(one_chip, (H,)), _sds(one_chip, (B, S, 1, N)),
              _sds(one_chip, (B, S, 1, N)))
+
+
+def test_fft_cyclic_layout_compiles_without_gather(one_chip):
+    """bsp_fft's cyclic layout at 2^28 points over 4 chips, on chip 0:
+    strided slices, no gather, and no lane-padded [n/p, p] buffer."""
+    x = _sds(one_chip, (1 << 28,), jnp.complex64)
+    compiled = _strided_layout.lower(x, 4).compile()
+    assert not re.search(r"\bgather\(", compiled.as_text())
+    assert compiled.memory_analysis().temp_size_in_bytes <= 3 * 2**30
