@@ -39,7 +39,10 @@ def blocked_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     """q [B, S, H, D]; k/v [B, S, Hkv, D] -> [B, S, H, D].
 
     Scans over query chunks; scores per step are [B, H, qc, S] — O(S)
-    memory in the sequence length, not O(S^2)."""
+    memory in the sequence length, not O(S^2).  Each step is
+    checkpointed, so the backward pass recomputes one chunk's scores at a
+    time instead of keeping every chunk's (at S = 8192 and 16 heads,
+    4.3 GB of f32 scores for one layer)."""
     B, S, H, D = q.shape
     Hkv = k.shape[2]
     group = H // Hkv
@@ -79,7 +82,7 @@ def blocked_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                        preferred_element_type=jnp.float32)
         return carry, o.astype(v.dtype)
 
-    _, outs = lax.scan(step, 0,
+    _, outs = lax.scan(jax.checkpoint(step, prevent_cse=False), 0,
                        (jnp.arange(nq), jnp.moveaxis(q4, 1, 0)))
     Dv = v.shape[-1]
     out = jnp.moveaxis(outs, 0, 1).reshape(B, nq * qc, H, Dv)

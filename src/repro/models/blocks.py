@@ -50,10 +50,10 @@ class Runtime:
         return self.mesh is not None and self.model_axis is not None
 
 
-def _norm(x, p, kind: str, plus_one: bool = False):
+def _norm(x, p, kind: str, plus_one: bool = False, eps: float = 1e-6):
     if kind == "layer":
         return layer_norm(x, p["w"], p["b"])
-    return rms_norm(x, p["w"], plus_one=plus_one)
+    return rms_norm(x, p["w"], eps=eps, plus_one=plus_one)
 
 
 def _norm_params(d: int, kind: str):
@@ -90,11 +90,15 @@ def _mla_params(key, cfg: ModelConfig, dtype) -> dict:
     m = cfg.mla
     ks = jax.random.split(key, 6)
     h = cfg.n_heads
+    dq = h * (m.dh_nope + m.dh_rope)
+    if m.q_lora:
+        p = {"wq_a": dense_init(ks[0], (cfg.d_model, m.q_lora), dtype=dtype),
+             "q_norm": jnp.ones((m.q_lora,), jnp.float32),
+             "wq_b": dense_init(ks[1], (m.q_lora, dq), dtype=dtype)}
+    else:
+        p = {"wq": dense_init(ks[0], (cfg.d_model, dq), dtype=dtype)}
     return {
-        "wq_a": dense_init(ks[0], (cfg.d_model, m.q_lora), dtype=dtype),
-        "q_norm": jnp.ones((m.q_lora,), jnp.float32),
-        "wq_b": dense_init(ks[1], (m.q_lora, h * (m.dh_nope + m.dh_rope)),
-                           dtype=dtype),
+        **p,
         "wkv_a": dense_init(ks[2], (cfg.d_model, m.kv_lora + m.dh_rope),
                             dtype=dtype),
         "kv_norm": jnp.ones((m.kv_lora,), jnp.float32),
@@ -136,16 +140,15 @@ def block_params(key, bcfg: BlockCfg, cfg: ModelConfig, dtype) -> dict:
     elif bcfg.ffn == "moe":
         p["moe"] = moe_params(ks[2], cfg.moe, dtype)
         p["ln2"] = _norm_params(cfg.d_model, cfg.norm)
-        if cfg.shared_expert:
-            # the shared expert is expert-sized (cfg.moe.d_ff), not d_ff
+        if cfg.n_shared:
+            # the shared experts, each expert-sized (cfg.moe.d_ff), run
+            # as one MLP of their summed width
+            f = cfg.n_shared * cfg.moe.d_ff
             kk = jax.random.split(ks[3], 3)
             p["shared_mlp"] = {
-                "w_gate": dense_init(kk[0], (cfg.d_model, cfg.moe.d_ff),
-                                     dtype=dtype),
-                "w_up": dense_init(kk[1], (cfg.d_model, cfg.moe.d_ff),
-                                   dtype=dtype),
-                "w_down": dense_init(kk[2], (cfg.moe.d_ff, cfg.d_model),
-                                     dtype=dtype),
+                "w_gate": dense_init(kk[0], (cfg.d_model, f), dtype=dtype),
+                "w_up": dense_init(kk[1], (cfg.d_model, f), dtype=dtype),
+                "w_down": dense_init(kk[2], (f, cfg.d_model), dtype=dtype),
             }
     if cfg.post_norms and bcfg.ffn != "none":
         p["post_ln2"] = _norm_params(cfg.d_model, cfg.norm)
@@ -193,12 +196,21 @@ def _attn_fwd(p, h, cfg: ModelConfig, bcfg: BlockCfg, positions,
     return o.reshape(B, S, cfg.n_heads * hd) @ p["wo"], (k, v)
 
 
+def _mla_q(p, h):
+    """MLA queries: through the low-rank q_lora path, or one plain ``wq``
+    where the model has no q_lora."""
+    if "wq" in p:
+        return h @ p["wq"]
+    return rms_norm(h @ p["wq_a"], p["q_norm"]) @ p["wq_b"]
+
+
+@jax.named_scope("mla")
 def _mla_fwd(p, h, cfg: ModelConfig, bcfg: BlockCfg, positions):
     """MLA training/prefill path (decompressed K/V)."""
     m = cfg.mla
     B, S, D = h.shape
     H = cfg.n_heads
-    q = rms_norm(h @ p["wq_a"], p["q_norm"]) @ p["wq_b"]
+    q = _mla_q(p, h)
     q = q.reshape(B, S, H, m.dh_nope + m.dh_rope)
     q_nope, q_rope = q[..., :m.dh_nope], q[..., m.dh_nope:]
     kv = h @ p["wkv_a"]
@@ -221,68 +233,47 @@ def _mla_fwd(p, h, cfg: ModelConfig, bcfg: BlockCfg, positions):
 
 
 def _ffn_fwd(p, h, cfg: ModelConfig, bcfg: BlockCfg, rt: Runtime):
+    """-> (output, aux): aux is the expert layer's balance loss and
+    counters (``moe_apply``), empty for a dense MLP."""
     if bcfg.ffn == "dense":
-        return _mlp(p["mlp"], h)
-    out = moe_apply(p["moe"], h, cfg.moe, mesh=rt.mesh,
-                    model_axis=rt.model_axis or "model",
-                    dp_axes=rt.dp_axes) if rt.distributed else \
-        _moe_single(p["moe"], h, cfg.moe)
-    if cfg.shared_expert:
+        return _mlp(p["mlp"], h), {}
+    out, bal, stats = moe_apply(
+        p["moe"], h, cfg.moe, mesh=rt.mesh if rt.distributed else None,
+        model_axis=rt.model_axis or "model", dp_axes=rt.dp_axes)
+    if cfg.n_shared:
         out = out + _mlp(p["shared_mlp"], h)
-    return out
-
-
-def _moe_single(p, x, mcfg) -> jnp.ndarray:
-    """Single-device MoE fallback (smoke tests without a mesh)."""
-    B, S, D = x.shape
-    T = B * S
-    xt = x.reshape(T, D)
-    logits = xt.astype(jnp.float32) @ p["router"]
-    E = p["w_gate"].shape[0]
-    if E > mcfg.n_experts:
-        logits = jnp.where(jnp.arange(E)[None] >= mcfg.n_experts, -1e30,
-                           logits)
-    gate_vals, gate_idx = lax.top_k(logits, min(mcfg.top_k, E))
-    gates = jax.nn.softmax(gate_vals, axis=-1)
-    out = jnp.zeros((T, D), jnp.float32)
-    cap = max(1, min(T, max(8, int(mcfg.capacity_factor * mcfg.top_k * T / E))))
-    for e in range(E):
-        w_tok = jnp.sum(jnp.where(gate_idx == e, gates, 0.0), axis=1)
-        sel_w, sel_idx = lax.top_k(w_tok, cap)
-        x_e = jnp.take(xt, sel_idx, axis=0)
-        y = (jax.nn.silu(x_e @ p["w_gate"][e]) * (x_e @ p["w_up"][e])) \
-            @ p["w_down"][e]
-        out = out.at[sel_idx].add(y.astype(jnp.float32) * sel_w[:, None])
-    return out.reshape(B, S, D).astype(x.dtype)
+    return out, {"bal": bal, **stats}
 
 
 def block_apply(p: dict, x: jnp.ndarray, bcfg: BlockCfg, cfg: ModelConfig,
-                rt: Runtime, positions, enc_out=None) -> jnp.ndarray:
+                rt: Runtime, positions, enc_out=None) -> tuple:
+    """-> (x, aux): aux as ``_ffn_fwd`` gives it."""
     plus_one = cfg.norm == "rms"
+    aux = {}
     if bcfg.mixer in ("attn", "mla"):
-        h = _norm(x, p["ln1"], cfg.norm, plus_one)
+        h = _norm(x, p["ln1"], cfg.norm, plus_one, cfg.norm_eps)
         if bcfg.mixer == "attn":
             o, _ = _attn_fwd(p["attn"], h, cfg, bcfg, positions)
         else:
             o, _ = _mla_fwd(p["attn"], h, cfg, bcfg, positions)
         if cfg.post_norms:
-            o = _norm(o, p["post_ln1"], cfg.norm, plus_one)
+            o = _norm(o, p["post_ln1"], cfg.norm, plus_one, cfg.norm_eps)
         x = x + o
     elif bcfg.mixer == "mamba":
-        h = _norm(x, p["ln1"], cfg.norm, plus_one)
+        h = _norm(x, p["ln1"], cfg.norm, plus_one, cfg.norm_eps)
         x = x + mamba_apply(p["mamba"], h, cfg.mamba)
     if bcfg.cross_attn:
-        h = _norm(x, p["ln_x"], cfg.norm, plus_one)
+        h = _norm(x, p["ln_x"], cfg.norm, plus_one, cfg.norm_eps)
         o, _ = _attn_fwd(p["xattn"], h, cfg, bcfg, positions,
                          kv_override=enc_out)
         x = x + o
     if bcfg.ffn != "none":
-        h = _norm(x, p["ln2"], cfg.norm, plus_one)
-        o = _ffn_fwd(p, h, cfg, bcfg, rt)
+        h = _norm(x, p["ln2"], cfg.norm, plus_one, cfg.norm_eps)
+        o, aux = _ffn_fwd(p, h, cfg, bcfg, rt)
         if cfg.post_norms:
-            o = _norm(o, p["post_ln2"], cfg.norm, plus_one)
+            o = _norm(o, p["post_ln2"], cfg.norm, plus_one, cfg.norm_eps)
         x = x + o
-    return x
+    return x, aux
 
 
 # --------------------------------------------------------------------------
@@ -360,8 +351,7 @@ def _mla_decode(p, h, cache, cfg: ModelConfig, rt: Runtime, pos):
     m = cfg.mla
     B, D = h.shape
     H = cfg.n_heads
-    q = rms_norm(h @ p["wq_a"], p["q_norm"]) @ p["wq_b"]
-    q = q.reshape(B, H, m.dh_nope + m.dh_rope)
+    q = _mla_q(p, h).reshape(B, H, m.dh_nope + m.dh_rope)
     q_nope, q_rope = q[..., :m.dh_nope], q[..., m.dh_nope:]
     posb = jnp.broadcast_to(pos, (B, 1))
     q_rope = apply_rope(q_rope[:, None], posb, cfg.rope_theta)[:, 0]
@@ -414,30 +404,30 @@ def block_decode(p: dict, x: jnp.ndarray, cache: dict, bcfg: BlockCfg,
     plus_one = cfg.norm == "rms"
     newc = dict(cache)
     if bcfg.mixer in ("attn", "mla"):
-        h = _norm(x, p["ln1"], cfg.norm, plus_one)
+        h = _norm(x, p["ln1"], cfg.norm, plus_one, cfg.norm_eps)
         if bcfg.mixer == "attn":
             o, upd = _attn_decode(p["attn"], h, cache, cfg, bcfg, rt, pos)
         else:
             o, upd = _mla_decode(p["attn"], h, cache, cfg, rt, pos)
         newc.update(upd)
         if cfg.post_norms:
-            o = _norm(o, p["post_ln1"], cfg.norm, plus_one)
+            o = _norm(o, p["post_ln1"], cfg.norm, plus_one, cfg.norm_eps)
         x = x + o
     elif bcfg.mixer == "mamba":
-        h = _norm(x, p["ln1"], cfg.norm, plus_one)
+        h = _norm(x, p["ln1"], cfg.norm, plus_one, cfg.norm_eps)
         o, upd = mamba_decode_step(p["mamba"], h, cache, cfg.mamba)
         newc.update(upd)
         x = x + o
     if bcfg.cross_attn:
-        h = _norm(x, p["ln_x"], cfg.norm, plus_one)
+        h = _norm(x, p["ln_x"], cfg.norm, plus_one, cfg.norm_eps)
         o, _ = _attn_fwd(p["xattn"], h[:, None], cfg, bcfg,
                          jnp.zeros((x.shape[0], 1), jnp.int32),
                          kv_override=enc_out)
         x = x + o[:, 0]
     if bcfg.ffn != "none":
-        h = _norm(x, p["ln2"], cfg.norm, plus_one)
-        o = _ffn_fwd(p, h[:, None], cfg, bcfg, rt)[:, 0]
+        h = _norm(x, p["ln2"], cfg.norm, plus_one, cfg.norm_eps)
+        o = _ffn_fwd(p, h[:, None], cfg, bcfg, rt)[0][:, 0]
         if cfg.post_norms:
-            o = _norm(o, p["post_ln2"], cfg.norm, plus_one)
+            o = _norm(o, p["post_ln2"], cfg.norm, plus_one, cfg.norm_eps)
         x = x + o
     return x, newc

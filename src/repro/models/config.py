@@ -22,7 +22,7 @@ __all__ = ["BlockCfg", "Group", "MLACfg", "ModelConfig"]
 
 @dataclasses.dataclass(frozen=True)
 class MLACfg:
-    q_lora: int = 1536
+    q_lora: Optional[int] = 1536   # None: queries from one plain ``wq``
     kv_lora: int = 512
     dh_nope: int = 128
     dh_rope: int = 64
@@ -66,6 +66,7 @@ class ModelConfig:
     attn_softcap: Optional[float] = None
     logit_softcap: Optional[float] = None
     norm: str = "rms"              # rms | layer
+    norm_eps: float = 1e-6         # epsilon of the block and final RMSNorms
     post_norms: bool = False       # gemma-2 sandwich norms
     pos_embed: str = "rope"        # rope | sinusoidal | learned | none
     tie_embeddings: bool = False
@@ -73,7 +74,7 @@ class ModelConfig:
     moe: Optional[MoEConfig] = None
     mla: Optional[MLACfg] = None
     mamba: Optional[MambaConfig] = None
-    shared_expert: bool = False    # deepseek shared expert alongside MoE
+    n_shared: int = 0              # deepseek shared experts alongside MoE
     # enc-dec
     encoder_groups: Tuple[Group, ...] = ()
     # modality stub: input embeddings are provided directly for the first

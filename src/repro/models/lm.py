@@ -25,8 +25,10 @@ from .blocks import (Runtime, block_apply, block_decode, block_init_cache,
                      block_params)
 from .common import dense_init, layer_norm, rms_norm, sinusoidal_positions
 from .config import Group, ModelConfig
+from .moe import bias_step
 
-__all__ = ["init_params", "forward", "loss_fn", "init_caches",
+__all__ = ["init_params", "forward", "loss_fn", "loss_and_stats",
+           "update_router_bias", "matrix_mask", "init_caches",
            "prefill", "decode_step", "count_params", "model_flops"]
 
 
@@ -87,36 +89,54 @@ def init_params(key, cfg: ModelConfig) -> dict:
 # scan-group execution
 # --------------------------------------------------------------------------
 
-def _cast_params(tree, cdt):
-    """Cast weight matrices to the compute dtype; norms/scalars stay f32.
-    Applied per-layer *inside* scan bodies so the FSDP all-gather moves
-    bf16 and the backward's reduce-scatter stays inside the loop (casting
-    the whole stacked tree outside the scan strands an unsharded f32
-    gradient accumulator)."""
-    def one(a):
+def _cast_params(tree, cdt, in_loop: bool = False):
+    """Cast weight matrices to the compute dtype; norms/scalars stay f32,
+    and so does an MoE router, whose top-k must not turn on the
+    weights' rounding.  Applied per-layer *inside* scan bodies so the
+    FSDP all-gather moves bf16 and the backward's reduce-scatter stays
+    inside the loop (casting the whole stacked tree outside the scan
+    strands an unsharded f32 gradient accumulator).
+
+    ``in_loop``: the tree is one layer's slice of stacked weights.  XLA
+    moves a bare narrowing cast ahead of the loop's slicing, which makes
+    a compute-dtype copy of every layer's weights at once (1.3 GB of
+    Moonlight's one-chip share); rounding to the compute dtype first
+    (``reduce_precision``, after which the cast is exact) keeps the cast
+    in the loop."""
+    def one(path, a):
+        if path and getattr(path[-1], "key", None) == "router":
+            return a
         if a.dtype == jnp.int8 and a.ndim > 1:
             # serving quantization: int8-at-rest, dequantised at use (the
             # per-tensor scale is folded into the stored values for the
             # dry-run; a production loader carries explicit scales)
             return a.astype(cdt) * jnp.asarray(0.01, cdt)
         if a.dtype in (jnp.float32, jnp.bfloat16) and a.ndim > 1:
+            if in_loop and a.dtype != cdt:
+                fi = jnp.finfo(cdt)
+                a = lax.reduce_precision(a, fi.nexp, fi.nmant)
             return a.astype(cdt)
         return a
-    return jax.tree.map(one, tree)
+    return jax.tree_util.tree_map_with_path(one, tree)
 
 
 def _scan_group(gp, x, g: Group, cfg: ModelConfig, rt: Runtime, positions,
                 enc_out=None):
+    """-> (x, aux): aux maps ``b{i}`` of each expert block to its
+    ``block_apply`` aux, stacked over the group's repeats."""
     cdt = _dtype(cfg.compute_dtype)
 
     def body(carry, layer_p):
         h = carry
-        layer_p = _cast_params(layer_p, cdt)
+        layer_p = _cast_params(layer_p, cdt, in_loop=True)
+        aux = {}
         for i, b in enumerate(g.blocks):
-            h = block_apply(layer_p[f"b{i}"], h, b, cfg, rt, positions,
-                            enc_out)
+            h, a = block_apply(layer_p[f"b{i}"], h, b, cfg, rt, positions,
+                               enc_out)
+            if a:
+                aux[f"b{i}"] = a
         # the carry is what remat saves per layer: keep it SP-sharded
-        return _constrain_act(h, rt), None
+        return _constrain_act(h, rt), aux
 
     if cfg.remat == "full":
         body = jax.checkpoint(body,
@@ -127,11 +147,12 @@ def _scan_group(gp, x, g: Group, cfg: ModelConfig, rt: Runtime, positions,
     if cfg.unroll_layers:
         # python loop: identical math, layer-count-proportional HLO (used
         # by the dry-run's cost-analysis calibration; see launch/dryrun)
+        auxs = []
         for l in range(g.repeats):
-            x, _ = body(x, jax.tree.map(lambda a: a[l], gp))
-        return x
-    x, _ = lax.scan(body, x, gp)
-    return x
+            x, a = body(x, jax.tree.map(lambda a: a[l], gp))
+            auxs.append(a)
+        return x, jax.tree.map(lambda *ls: jnp.stack(ls), *auxs)
+    return lax.scan(body, x, gp)
 
 
 def _constrain_act(x, rt: Runtime):
@@ -159,7 +180,7 @@ def _constrain_act(x, rt: Runtime):
 def _final_norm(x, p, cfg):
     if cfg.norm == "layer":
         return layer_norm(x, p["w"], p["b"])
-    return rms_norm(x, p["w"], plus_one=True)
+    return rms_norm(x, p["w"], eps=cfg.norm_eps, plus_one=True)
 
 
 def _embed_tokens(params, tokens, cfg: ModelConfig):
@@ -195,8 +216,8 @@ def _run_encoder(params, frames, cfg: ModelConfig, rt: Runtime):
     positions = jnp.broadcast_to(jnp.arange(S)[None], x.shape[:2])
     x = _constrain_act(x, rt)
     for g in cfg.encoder_groups:
-        x = _scan_group(params[f"enc_{g.name}"], x, g, cfg, rt, positions,
-                        None)
+        x, _ = _scan_group(params[f"enc_{g.name}"], x, g, cfg, rt, positions,
+                           None)
         x = _constrain_act(x, rt)
     return _final_norm(x, params["enc_final_norm"], cfg)
 
@@ -204,6 +225,13 @@ def _run_encoder(params, frames, cfg: ModelConfig, rt: Runtime):
 def forward(params, batch: dict, cfg: ModelConfig, rt: Runtime
             ) -> jnp.ndarray:
     """Training/prefill forward -> logits [B, S, V] (f32)."""
+    return _forward(params, batch, cfg, rt)[0]
+
+
+def _forward(params, batch: dict, cfg: ModelConfig, rt: Runtime):
+    """-> (logits, or (logits, MTP logits), aux): aux maps the parameter
+    keys of expert blocks (``dec_<group>``, ``mtp_block``) to their
+    balance losses and counters."""
     cdt = _dtype(cfg.compute_dtype)
     params = {k: (v if k.startswith(("dec_", "enc_")) else
                   _cast_params(v, cdt)) for k, v in params.items()}
@@ -223,9 +251,12 @@ def forward(params, batch: dict, cfg: ModelConfig, rt: Runtime
         enc_out = _run_encoder(params, batch["frames"], cfg, rt)
 
     x = _constrain_act(x, rt)
+    aux = {}
     for g in cfg.groups:
-        x = _scan_group(params[f"dec_{g.name}"], x, g, cfg, rt, positions,
-                        enc_out)
+        x, a = _scan_group(params[f"dec_{g.name}"], x, g, cfg, rt, positions,
+                           enc_out)
+        if a:
+            aux[f"dec_{g.name}"] = a
         x = _constrain_act(x, rt)
     x = _final_norm(x, params["final_norm"], cfg)
     if cfg.modality == "vision" and "embeds" in batch:
@@ -236,17 +267,27 @@ def forward(params, batch: dict, cfg: ModelConfig, rt: Runtime
         emb_next = jnp.roll(_embed_tokens(params, tokens, cfg), -1, axis=1)
         h_mtp = jnp.concatenate([x.astype(cdt), emb_next.astype(cdt)],
                                 axis=-1) @ params["mtp_proj"]
-        h_mtp = block_apply(params["mtp_block"], h_mtp,
-                            cfg.groups[-1].blocks[-1], cfg, rt, positions)
+        h_mtp, a = block_apply(params["mtp_block"], h_mtp,
+                               cfg.groups[-1].blocks[-1], cfg, rt, positions)
+        if a:
+            aux["mtp_block"] = a
         logits_mtp = _head(params, _final_norm(
             h_mtp, params["final_norm"], cfg), cfg, rt)
-        return logits, logits_mtp
-    return logits
+        return (logits, logits_mtp), aux
+    return logits, aux
 
 
 def loss_fn(params, batch: dict, cfg: ModelConfig, rt: Runtime):
-    """Mean next-token cross-entropy (labels -1 are masked)."""
-    out = forward(params, batch, cfg, rt)
+    """The training loss (``loss_and_stats``)."""
+    return loss_and_stats(params, batch, cfg, rt)[0]
+
+
+def loss_and_stats(params, batch: dict, cfg: ModelConfig, rt: Runtime):
+    """-> (loss, stats).  The loss is the mean next-token cross-entropy
+    (labels -1 are masked), plus 0.3 of the MTP head's, plus every
+    expert layer's balance loss.  ``stats`` holds each expert layer's
+    counters (``moe_apply``), keyed as the parameters are."""
+    out, aux = _forward(params, batch, cfg, rt)
     logits_mtp = None
     if cfg.mtp:
         logits, logits_mtp = out
@@ -270,7 +311,48 @@ def loss_fn(params, batch: dict, cfg: ModelConfig, rt: Runtime):
         labels2 = jnp.concatenate(
             [labels[:, 1:], jnp.full_like(labels[:, :1], -1)], axis=1)
         loss = loss + 0.3 * xent(logits_mtp, labels2)
-    return loss
+    bal = [a["bal"] for a in jax.tree.leaves(
+        aux, is_leaf=lambda t: isinstance(t, dict) and "bal" in t)]
+    loss = loss + sum(jnp.sum(b) for b in bal)
+    stats = jax.tree.map(lambda t: {k: v for k, v in t.items() if k != "bal"},
+                         aux, is_leaf=lambda t: isinstance(t, dict)
+                         and "bal" in t)
+    return loss, stats
+
+
+def matrix_mask(params) -> dict:
+    """True for each weight matrix, False for norms, biases and the
+    routers' selection biases: the leaves AdamW decays.  A scan group's
+    leaves carry a leading layer axis, which does not count."""
+    def one(path, a):
+        stacked = str(getattr(path[0], "key", "")).startswith(("dec_",
+                                                                "enc_"))
+        return a.ndim - stacked > 1
+    return jax.tree_util.tree_map_with_path(one, params)
+
+
+def update_router_bias(params, stats, cfg: ModelConfig):
+    """Move each expert layer's selection bias against the load its
+    router counted in the step (``moe.bias_step``); ``stats`` as
+    ``loss_and_stats`` returns them.  Models whose router has no bias
+    (softmax scoring) pass through."""
+    if cfg.moe is None or cfg.moe.scoring != "sigmoid":
+        return params
+
+    def block(p, st):
+        moe = dict(p["moe"])
+        moe["e_score_correction_bias"] = bias_step(
+            moe["e_score_correction_bias"], st["load"])
+        return {**p, "moe": moe}
+
+    new = dict(params)
+    for key, st in stats.items():
+        if key == "mtp_block":
+            new[key] = block(params[key], st)
+        else:
+            new[key] = {**params[key], **{b: block(params[key][b], s)
+                                          for b, s in st.items()}}
+    return new
 
 
 # --------------------------------------------------------------------------
@@ -312,7 +394,7 @@ def decode_step(params, token: jnp.ndarray, caches: dict, pos, cfg: ModelConfig,
         def body(carry, xs):
             h = carry
             layer_p, layer_c = xs
-            layer_p = _cast_params(layer_p, cdt)
+            layer_p = _cast_params(layer_p, cdt, in_loop=True)
             newc = {}
             for i, b in enumerate(g.blocks):
                 h, c = block_decode(layer_p[f"b{i}"], h, layer_c[f"b{i}"],

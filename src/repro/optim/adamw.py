@@ -42,8 +42,11 @@ def global_norm(tree) -> jnp.ndarray:
                         for g in jax.tree.leaves(tree)))
 
 
-def adamw_update(grads, state, params, cfg: AdamWConfig = AdamWConfig()):
-    """Returns (new_params, new_state, metrics)."""
+def adamw_update(grads, state, params, cfg: AdamWConfig = AdamWConfig(),
+                 decay=None):
+    """Returns (new_params, new_state, metrics).  ``decay``: a tree of
+    bools congruent with ``params``, the leaves that take weight decay;
+    None decays every leaf of more than one dimension."""
     step = state["step"] + 1
     gnorm = global_norm(grads)
     if cfg.clip_norm is not None:
@@ -54,24 +57,26 @@ def adamw_update(grads, state, params, cfg: AdamWConfig = AdamWConfig()):
     c1 = 1.0 - b1 ** step.astype(jnp.float32)
     c2 = 1.0 - b2 ** step.astype(jnp.float32)
 
-    def upd(p, g, m, v):
+    def upd(p, g, m, v, dec):
         gf = g.astype(cfg.moment_dtype)
         m2 = b1 * m + (1 - b1) * gf
         v2 = b2 * v + (1 - b2) * gf * gf
         mh = m2 / c1
         vh = v2 / c2
         delta = mh / (jnp.sqrt(vh) + cfg.eps)
-        decay = cfg.weight_decay * p.astype(jnp.float32) \
-            if p.ndim > 1 else 0.0   # no decay on norms/biases
-        new_p = p.astype(jnp.float32) - lr * (delta + decay)
+        wd = cfg.weight_decay * p.astype(jnp.float32) if dec else 0.0
+        new_p = p.astype(jnp.float32) - lr * (delta + wd)
         return new_p.astype(p.dtype), m2, v2
 
     flat_p, treedef = jax.tree.flatten(params)
     flat_g = jax.tree.leaves(grads)
     flat_m = jax.tree.leaves(state["m"])
     flat_v = jax.tree.leaves(state["v"])
-    out = [upd(p, g, m, v) for p, g, m, v in
-           zip(flat_p, flat_g, flat_m, flat_v)]
+    # no decay on norms/biases
+    flat_d = [p.ndim > 1 for p in flat_p] if decay is None \
+        else jax.tree.leaves(decay)
+    out = [upd(p, g, m, v, d) for p, g, m, v, d in
+           zip(flat_p, flat_g, flat_m, flat_v, flat_d)]
     new_params = jax.tree.unflatten(treedef, [o[0] for o in out])
     new_state = {
         "m": jax.tree.unflatten(treedef, [o[1] for o in out]),

@@ -25,7 +25,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.bsp.pod_sync import pod_allreduce
 from repro.core import CostLedger, LPF_SYNC_DEFAULT, SyncAttributes, compat
-from repro.models import Runtime, init_params, loss_fn, decode_step, init_caches
+from repro.models import (Runtime, decode_step, init_caches, init_params,
+                          loss_and_stats, matrix_mask, update_router_bias)
 from repro.models.config import ModelConfig
 from repro.optim import AdamWConfig, adamw_init, adamw_update
 from repro.sharding.rules import batch_specs, cache_specs, param_specs
@@ -69,7 +70,10 @@ def _restack_scan_layers(split: dict, split_keys) -> dict:
 @dataclasses.dataclass
 class TrainStep:
     """Compiled pieces + specs (also consumed by dryrun/roofline)."""
-    step_fn: Any                 # (params, opt, batch) -> (params, opt, metrics)
+    # (params, opt, batch) -> (params, opt, metrics); metrics: loss,
+    # grad_norm, lr, and for expert models ``moe``, each expert layer's
+    # counters (``moe_apply``) keyed as the parameters are
+    step_fn: Any
     init_fn: Any                 # (key) -> (params, opt)
     param_sharding: Any
     opt_sharding: Any
@@ -110,6 +114,7 @@ def build_train_step(cfg: ModelConfig, mesh, *,
     key = jax.random.PRNGKey(0)
 
     p_shapes = jax.eval_shape(partial(init_params, cfg=cfg), key)
+    decay = matrix_mask(p_shapes)
     pspecs = param_specs(p_shapes, mesh, axes=param_axes)
     o_shapes = jax.eval_shape(partial(adamw_init, cfg=opt_cfg), p_shapes)
     ospecs = param_specs(o_shapes, mesh, axes=param_axes)
@@ -125,15 +130,16 @@ def build_train_step(cfg: ModelConfig, mesh, *,
                 g, NamedSharding(mesh, s)), grads, pspecs)
 
     def loss_and_grads(params, batch, rt_=None, constrain=True):
-        """Microbatched (gradient-accumulated) loss/grads: activation
-        memory scales by 1/k at unchanged arithmetic — how the widest
-        configs fit 16 GB/chip at global batch 256."""
+        """Microbatched (gradient-accumulated) loss, expert counters and
+        grads: activation memory scales by 1/k at unchanged arithmetic —
+        how the widest configs fit 16 GB/chip at global batch 256."""
         rt_ = rt_ or rt
         cg = constrain_grads if constrain else (lambda g: g)
+        value_and_grad = jax.value_and_grad(
+            lambda p, b: loss_and_stats(p, b, cfg, rt_), has_aux=True)
         if grad_accum <= 1:
-            loss, grads = jax.value_and_grad(
-                lambda p: loss_fn(p, batch, cfg, rt_))(params)
-            return loss, cg(grads)
+            (loss, stats), grads = value_and_grad(params, batch)
+            return loss, stats, cg(grads)
 
         micro = jax.tree.map(
             lambda l: l.reshape((grad_accum, l.shape[0] // grad_accum)
@@ -141,25 +147,33 @@ def build_train_step(cfg: ModelConfig, mesh, *,
 
         def acc_step(carry, mb):
             loss_acc, g_acc = carry
-            loss, grads = jax.value_and_grad(
-                lambda p: loss_fn(p, mb, cfg, rt_))(params)
+            (loss, stats), grads = value_and_grad(params, mb)
             grads = cg(grads)
             g_acc = jax.tree.map(lambda a, g: a + g.astype(a.dtype),
                                  g_acc, grads)
-            return (loss_acc + loss, g_acc), None
+            return (loss_acc + loss, g_acc), stats
 
         g0 = cg(jax.tree.map(
             lambda p: jnp.zeros(p.shape, jnp.float32), params))
-        (loss_sum, g_sum), _ = jax.lax.scan(
+        (loss_sum, g_sum), stats = jax.lax.scan(
             acc_step, (jnp.zeros((), jnp.float32), g0), micro)
         k = float(grad_accum)
-        return loss_sum / k, jax.tree.map(lambda g: g / k, g_sum)
+        stats = jax.tree.map(lambda c: jnp.sum(c, axis=0), stats)
+        return loss_sum / k, stats, jax.tree.map(lambda g: g / k, g_sum)
+
+    def update(params, opt, loss, stats, grads):
+        """AdamW, then the routers' selection biases, which take no
+        gradient and no decay: each moves against its layer's load."""
+        params, opt, metrics = adamw_update(grads, opt, params, opt_cfg,
+                                            decay)
+        params = update_router_bias(params, stats, cfg)
+        metrics["loss"] = loss
+        if stats:
+            metrics["moe"] = stats
+        return params, opt, metrics
 
     def plain_step(params, opt, batch):
-        loss, grads = loss_and_grads(params, batch)
-        params, opt, metrics = adamw_update(grads, opt, params, opt_cfg)
-        metrics["loss"] = loss
-        return params, opt, metrics
+        return update(params, opt, *loss_and_grads(params, batch))
 
     if grad_sync == "lpf" and npods > 1:
         # XLA workaround: with_sharding_constraint over Auto axes inside a
@@ -171,8 +185,8 @@ def build_train_step(cfg: ModelConfig, mesh, *,
         rt_pod = Runtime()
 
         def pod_body(params, opt, batch):
-            loss, grads = loss_and_grads(params, batch, rt_pod,
-                                         constrain=False)
+            loss, stats, grads = loss_and_grads(params, batch, rt_pod,
+                                                constrain=False)
             # default ``auto`` picks the overlapped bucket pipeline when
             # ``grad_bucket_bytes`` is set (bucket k+1's reduce-scatter
             # under bucket k's all-gather), one fused reduce-scatter+
@@ -200,9 +214,8 @@ def build_train_step(cfg: ModelConfig, mesh, *,
                                       method=grad_sync_method,
                                       bucket_bytes=grad_bucket_bytes)
             loss = jax.lax.pmean(loss, "pod")
-            params, opt, metrics = adamw_update(grads, opt, params, opt_cfg)
-            metrics["loss"] = loss
-            return params, opt, metrics
+            stats = jax.lax.psum(stats, "pod")
+            return update(params, opt, loss, stats, grads)
 
         rep = lambda tree: jax.tree.map(lambda _: P(), tree)
 
@@ -212,8 +225,7 @@ def build_train_step(cfg: ModelConfig, mesh, *,
             fn = compat.shard_map(
                 pod_body, mesh=mesh,
                 in_specs=(rep(params), rep(opt), bspecs),
-                out_specs=(rep(params), rep(opt),
-                           {"grad_norm": P(), "lr": P(), "loss": P()}),
+                out_specs=(rep(params), rep(opt), P()),
                 axis_names={"pod"}, check_vma=False)
             return fn(params, opt, batch)
     else:

@@ -6,7 +6,9 @@ with no chip attached, and refuses what the chip would refuse — shapes
 it cannot lay out, operations it cannot lower, more VMEM than a kernel
 may use.  The interpret-mode tests cannot see any of that.  One more
 test compiles ``bsp_fft``'s cyclic layout, which is no kernel, to see
-that it runs as strided slices on the chip rather than as a gather.
+that it runs as strided slices on the chip rather than as a gather, and
+one compiles the benchmark's Moonlight training step, which is no kernel
+either, to see that it fits one chip's memory.
 
 Widths are the ones ``chip_smoke.py`` runs: flash attention at
 llama3.2-1b (H=32, Hkv=8, S=2048, D=64, bf16), ``fft_stage`` on
@@ -24,8 +26,10 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, NamedSharding, PartitionSpec, \
+    SingleDeviceSharding
 
 from repro.algorithms.fft import _strided_layout
 from repro.kernels.fft_stage import kernel as fft_kernel
@@ -108,3 +112,40 @@ def test_fft_cyclic_layout_compiles_without_gather(one_chip):
     compiled = _strided_layout.lower(x, 4).compile()
     assert not re.search(r"\bgather\(", compiled.as_text())
     assert compiled.memory_analysis().temp_size_in_bytes <= 3 * 2**30
+
+
+def test_moonlight_train_step_fits_one_chip(topo):
+    """The cell ``moonlight-16b-a3b-ep8.train8k``'s step at the published
+    widths: one chip's share of an 8-way expert-parallel layer (the dense
+    layer and 5 expert layers, 8 of 64 experts, 20,480 token ids), one
+    sequence of 8,192 tokens, f32 parameters and AdamW moments.  Its
+    arguments and temporaries fit in 15 GB of the chip's 16, the held
+    experts run as grouped matmuls, and the layer loop casts each
+    layer's weights inside it: no bfloat16 copy of the 5 expert layers'
+    stacked expert weights."""
+    from repro.configs import expert_share, get_config
+    from repro.models import init_params
+    from repro.optim import adamw_init
+    from repro.runtime.train_step import build_train_step
+
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"))
+    cfg = expert_share(get_config("moonlight-16b-a3b", ep_degree=1),
+                       layers=6, experts_held=8, vocab=20480)
+    ts = build_train_step(cfg, mesh)
+    params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
+    opt = jax.eval_shape(lambda: adamw_init(params))
+
+    def placed(tree, shardings):
+        return jax.tree.map(lambda a, s: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=s), tree, shardings)
+
+    tokens = jax.ShapeDtypeStruct((1, 8192), jnp.int32,
+                                  sharding=NamedSharding(mesh, PartitionSpec()))
+    compiled = ts.step_fn.lower(
+        placed(params, ts.param_sharding), placed(opt, ts.opt_sharding),
+        {"tokens": tokens, "labels": tokens}).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes <= 15e9
+    text = compiled.as_text()
+    assert "ragged-dot" in text
+    assert not re.search(r"bf16\[5,8,2048,1408\]", text)

@@ -109,7 +109,7 @@ def test_param_counts_match_published():
         "gemma2-9b": 9.2e9, "deepseek-v3-671b": 682e9,
         "mamba2-130m": 0.13e9, "llava-next-mistral-7b": 7.2e9,
         "jamba-v0.1-52b": 51.5e9, "whisper-base": 0.106e9,  # +32k learned positions
-        "granite-moe-3b-a800m": 3.9e9,
+        "granite-moe-3b-a800m": 3.9e9, "moonlight-16b-a3b": 15.96e9,
     }
     for arch, n in expect.items():
         got = count_params(get_config(arch))
@@ -121,3 +121,5 @@ def test_active_params_moe():
     assert 34e9 < ds < 42e9                 # ~37B active
     ja = count_params(get_config("jamba-v0.1-52b"), active_only=True)
     assert 10e9 < ja < 14e9                 # ~12B active
+    ml = count_params(get_config("moonlight-16b-a3b"), active_only=True)
+    assert 2.89e9 < ml < 2.95e9             # 2.92B active
