@@ -174,6 +174,21 @@ def bsp_fft_spmd(ctx: LPFContext, x_local: jnp.ndarray, n: int, *,
     return yl / n if inverse else yl
 
 
+@functools.lru_cache(maxsize=256)
+def _fft_exec_spmd(n: int, p: int, ordered: bool, use_kernel: bool,
+                   attrs: SyncAttributes, inverse: bool):
+    """``bsp_fft``'s SPMD function, one per set of static parameters:
+    ``exec_`` keys its compiled program on the function."""
+
+    def spmd(ctx, s, pp, xt):
+        xl = xt.reshape(pp, n // pp)[s]
+        return bsp_fft_spmd(ctx, xl, n, ordered=ordered,
+                            use_kernel=use_kernel, attrs=attrs,
+                            inverse=inverse)
+
+    return spmd
+
+
 def bsp_fft(mesh: jax.sharding.Mesh, x: jnp.ndarray, *,
             axes: Optional[tuple] = None, ordered: bool = True,
             use_kernel: bool = False, inverse: bool = False,
@@ -193,13 +208,7 @@ def bsp_fft(mesh: jax.sharding.Mesh, x: jnp.ndarray, *,
     # tile, a 32 GiB buffer for complex64 at n = 2^28
     with span("lpf.fft.layout"):
         xc = cyclic_layout(x, p)
-
-    def spmd(ctx, s, pp, xt):
-        xl = xt.reshape(pp, n // pp)[s]
-        return bsp_fft_spmd(ctx, xl, n, ordered=ordered,
-                            use_kernel=use_kernel, attrs=attrs,
-                            inverse=inverse)
-
+    spmd = _fft_exec_spmd(n, p, ordered, use_kernel, attrs, inverse)
     out = exec_(mesh, spmd, jnp.asarray(xc), axes=axes,
                 out_specs=P(axes), return_ledger=return_ledger)
     if return_ledger:

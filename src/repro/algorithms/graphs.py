@@ -17,7 +17,8 @@ from typing import List, Tuple
 
 import numpy as np
 
-__all__ = ["rmat_graph", "banded_graph", "PartitionedGraph", "partition_graph"]
+__all__ = ["rmat_graph", "banded_graph", "GraphPlan", "PartitionedGraph",
+           "partition_graph"]
 
 
 def rmat_graph(n: int, m: int, seed: int = 0,
@@ -73,6 +74,18 @@ def banded_graph(n: int, band: int = 4) -> np.ndarray:
     return np.stack([src, dst], axis=1)
 
 
+@dataclasses.dataclass(frozen=True)
+class GraphPlan:
+    """What the PageRank trace reads from a :class:`PartitionedGraph`
+    besides its array shapes: sizes and the halo message table."""
+
+    n: int
+    p: int
+    rows: int
+    halo_max: int
+    msgs: Tuple[Tuple[int, int, int, int, int], ...]
+
+
 @dataclasses.dataclass
 class PartitionedGraph:
     """Block-row partitioned column-stochastic link matrix + halo plan.
@@ -102,6 +115,11 @@ class PartitionedGraph:
     pack_idx: np.ndarray
     dangling: np.ndarray
     msgs: List[Tuple[int, int, int, int, int]]
+
+    @property
+    def plan(self) -> GraphPlan:
+        return GraphPlan(self.n, self.p, self.rows, self.halo_max,
+                         tuple(self.msgs))
 
     def h_bytes(self, itemsize: int = 4) -> int:
         """The per-iteration halo h-relation (bytes) — the immortal cost."""

@@ -24,8 +24,9 @@ favour of Spark").
 
 from __future__ import annotations
 
+import functools
 import math
-from typing import Optional, Tuple
+from typing import Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -35,13 +36,13 @@ from jax.sharding import PartitionSpec as P
 from repro import bsp
 from repro.core import (LPFContext, LPF_SYNC_DEFAULT, SyncAttributes, exec_,
                         span)
-from .graphs import PartitionedGraph
+from .graphs import GraphPlan, PartitionedGraph
 
 __all__ = ["lpf_pagerank", "pagerank_spmd", "dataflow_pagerank",
            "reference_pagerank"]
 
 
-def _halo_exchange(ctx: LPFContext, g: PartitionedGraph,
+def _halo_exchange(ctx: LPFContext, g: Union[PartitionedGraph, GraphPlan],
                    r_local: jnp.ndarray,
                    attrs: SyncAttributes, pack_idx: jnp.ndarray
                    ) -> jnp.ndarray:
@@ -60,13 +61,15 @@ def _halo_exchange(ctx: LPFContext, g: PartitionedGraph,
     return halo
 
 
-def pagerank_spmd(ctx: LPFContext, g: PartitionedGraph, shard: dict, *,
+def pagerank_spmd(ctx: LPFContext, g: Union[PartitionedGraph, GraphPlan],
+                  shard: dict, *,
                   alpha: float = 0.85, tol: float = 1e-7,
                   max_iter: int = 200,
                   attrs: SyncAttributes = LPF_SYNC_DEFAULT
                   ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Run PageRank inside an SPMD region.
 
+    ``g``: the partitioned graph, or its :attr:`~PartitionedGraph.plan`.
     ``shard``: this process's rows of the stacked arrays (squeezed):
     row_ids/col_ext/vals [nnz_max], pack_idx [send_max], dangling [rows].
     Returns (r_local [rows], iterations, l1 residual).
@@ -137,6 +140,21 @@ def pagerank_spmd(ctx: LPFContext, g: PartitionedGraph, shard: dict, *,
     return r, iters, res
 
 
+@functools.lru_cache(maxsize=256)
+def _pagerank_exec_spmd(plan: GraphPlan, alpha: float, tol: float,
+                        max_iter: int, attrs: SyncAttributes):
+    """``lpf_pagerank``'s SPMD function, one per graph plan and solver
+    parameters: ``exec_`` keys its compiled program on the function.  It
+    holds the plan, not the graph, whose host arrays stay uncached."""
+
+    def spmd(ctx, s, p, a):
+        shard = {k: v.reshape(v.shape[1:]) for k, v in a.items()}
+        return pagerank_spmd(ctx, plan, shard, alpha=alpha, tol=tol,
+                             max_iter=max_iter, attrs=attrs)
+
+    return spmd
+
+
 def lpf_pagerank(mesh: jax.sharding.Mesh, g: PartitionedGraph, *,
                  axes: Optional[tuple] = None, alpha: float = 0.85,
                  tol: float = 1e-7, max_iter: int = 200,
@@ -151,12 +169,7 @@ def lpf_pagerank(mesh: jax.sharding.Mesh, g: PartitionedGraph, *,
             "dangling": jnp.asarray(g.dangling),
         }
     in_specs = {k: P(axes) for k in args}
-
-    def spmd(ctx, s, p, a):
-        shard = {k: v.reshape(v.shape[1:]) for k, v in a.items()}
-        return pagerank_spmd(ctx, g, shard, alpha=alpha, tol=tol,
-                             max_iter=max_iter, attrs=attrs)
-
+    spmd = _pagerank_exec_spmd(g.plan, alpha, tol, max_iter, attrs)
     r, iters, res = exec_(mesh, spmd, args, axes=axes,
                           in_specs=in_specs,
                           out_specs=(P(axes), P(), P()))

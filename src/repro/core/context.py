@@ -17,11 +17,14 @@ values are read back with ``value`` / ``tensor``.
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import dataclasses
 import os
+import threading
 import time
-from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple, Union
+from typing import (Any, Callable, Dict, Hashable, List, Optional, Sequence,
+                    Tuple, Union)
 
 import jax
 import jax.numpy as jnp
@@ -793,6 +796,70 @@ def rehook(ctx: LPFContext, spmd: Callable, args: Any = None, *,
         ctx._on_hold = False
 
 
+#: ``exec_``'s compiled programs by key (:func:`_exec_key`), least
+#: recently used first: (executable, cost ledger of its trace).  As many
+#: as a :class:`ProgramCache` holds.
+_EXEC_CACHE_SIZE = 256
+_EXEC_CACHE: "collections.OrderedDict[Hashable, Tuple[Any, CostLedger]]" = \
+    collections.OrderedDict()
+_EXEC_LOCK = threading.Lock()
+
+
+def _spec_key(specs: Any) -> Tuple[Any, Tuple[Any, ...]]:
+    leaves, tree = jax.tree.flatten(
+        specs, is_leaf=lambda s: s is None or isinstance(s, P))
+    return tree, tuple(leaves)
+
+
+def _leaf_key(x: Any) -> Tuple[Any, ...]:
+    aval = jax.typeof(x)
+    placement = (x.sharding, x.committed) if isinstance(x, jax.Array) \
+        else None
+    return aval.shape, aval.dtype, aval.weak_type, placement
+
+
+def _exec_key(spmd: Callable, mesh: jax.sharding.Mesh,
+              axes: Tuple[str, ...], in_specs: Any, out_specs: Any,
+              hardware: HardwareModel, args: Any) -> Optional[Hashable]:
+    """Everything that decides the program ``exec_`` compiles, with no
+    array in it; None where a part is unhashable."""
+    leaves, tree = jax.tree.flatten(args)
+    # what JAX and LPFContext read from the process while tracing
+    env = (jax.config.jax_enable_x64,
+           os.environ.get("LPF_SANITIZE", "0") != "0",
+           os.environ.get("LPF_COMPILE_PROGRAMS", "1") != "0")
+    key = (spmd, mesh, axes, _spec_key(in_specs), _spec_key(out_specs),
+           hardware, tree, tuple(_leaf_key(x) for x in leaves), env)
+    try:
+        hash(key)
+    except TypeError:
+        return None
+    return key
+
+
+def _exec_lookup(key: Hashable) -> Optional[Tuple[Any, CostLedger]]:
+    with _EXEC_LOCK:
+        entry = _EXEC_CACHE.get(key)
+        if entry is not None:
+            with span("lpf.exec.hit"):
+                _EXEC_CACHE.move_to_end(key)
+    return entry
+
+
+def _ledger_copy(ledger: CostLedger) -> CostLedger:
+    """A ledger of its own for each caller, so that none edits the kept one."""
+    out = CostLedger()
+    out.records = list(ledger.records)
+    return out
+
+
+def _exec_store(key: Hashable, entry: Tuple[Any, CostLedger]) -> None:
+    with _EXEC_LOCK:
+        _EXEC_CACHE[key] = entry
+        while len(_EXEC_CACHE) > _EXEC_CACHE_SIZE:
+            _EXEC_CACHE.popitem(last=False)
+
+
 def exec_(mesh: jax.sharding.Mesh, spmd: Callable, args: Any = None, *,
           axes: Optional[Sequence[str]] = None,
           in_specs: Any = None, out_specs: Any = P(),
@@ -808,10 +875,33 @@ def exec_(mesh: jax.sharding.Mesh, spmd: Callable, args: Any = None, *,
     time, for compliance checking.
 
     The call is staged (trace, lower, compile, run), each stage a span
-    of its own (:mod:`repro.core.spans`); nothing is kept across calls.
-    Inside a caller's trace (an enclosing ``jit``) it nests into that
-    trace, as :func:`hook` does, and has no stages of its own."""
+    of its own (:mod:`repro.core.spans`).  The executable and its ledger
+    are kept, in a process-wide LRU, under the call's key: ``spmd`` (by
+    hash and equality, as ``jax.jit`` keys a function), mesh, axes,
+    specs, hardware and the arguments' structure, shapes, dtypes and
+    placements.  A later call with an equal key runs the kept
+    executable (span ``lpf.exec.hit``, then ``lpf.exec.run``) and
+    returns the kept ledger; so pass a stable ``spmd``, not a closure
+    made anew per call.  Inside a caller's trace (an enclosing ``jit``)
+    it nests into that trace, as :func:`hook` does, and has no stages
+    of its own."""
     axes = tuple(axes) if axes is not None else tuple(mesh.axis_names)
+    if in_specs is None:
+        in_specs = compat.tree_map(lambda _: P(), args)
+    key = None
+    if jit and not compat.tracing():
+        key = _exec_key(spmd, mesh, axes, in_specs, out_specs, hardware,
+                        args)
+        # A hit skips LPF's trace-time work: planning, the program
+        # cache, certification and the linter.  That is sound because an
+        # equal key traces the same recorded program, which the miss
+        # that stored the entry planned and certified.
+        entry = _exec_lookup(key) if key is not None else None
+        if entry is not None:
+            compiled, ledger = entry
+            with span("lpf.exec.run"):
+                out = compiled(args)
+            return (out, _ledger_copy(ledger)) if return_ledger else out
     ledger_box: List[CostLedger] = []
 
     def wrapped(a):
@@ -819,8 +909,6 @@ def exec_(mesh: jax.sharding.Mesh, spmd: Callable, args: Any = None, *,
         ledger_box.append(ctx.ledger)
         return spmd(ctx, ctx.pid, ctx.p, a)
 
-    if in_specs is None:
-        in_specs = compat.tree_map(lambda _: P(), args)
     fn = compat.shard_map(wrapped, mesh=mesh, in_specs=(in_specs,),
                           out_specs=out_specs, check_vma=False)
     if compat.tracing():
@@ -832,6 +920,9 @@ def exec_(mesh: jax.sharding.Mesh, spmd: Callable, args: Any = None, *,
             lowered = traced.lower()
         with span("lpf.exec.compile"):
             compiled = lowered.compile()
+        if key is not None:
+            _exec_store(key, (compiled, _ledger_copy(
+                ledger_box[0] if ledger_box else CostLedger())))
         with span("lpf.exec.run"):
             out = compiled(args)
     else:

@@ -49,6 +49,12 @@ class HardwareModel:
     vmem_bytes: float                  # on-chip vector memory
     links: Mapping[str, LinkModel]     # kind -> link model ("ici", "dcn", "host")
 
+    def __hash__(self) -> int:
+        # the links mapping hashes by its items: equal models hash alike
+        return hash((self.name, self.peak_flops_bf16, self.peak_flops_fp32,
+                     self.hbm_bw, self.hbm_bytes, self.vmem_bytes,
+                     tuple(sorted(self.links.items()))))
+
     def link(self, kind: str) -> LinkModel:
         if kind not in self.links:
             raise KeyError(f"{self.name} has no link class {kind!r}")

@@ -22,6 +22,8 @@ The program's spans:
   executable from the persistent compilation cache or compiling it, and
   placing the arguments and dispatching (with ``jit=False``, the whole
   eager call);
+* ``lpf.exec.hit``: ``exec_`` found the executable of an equal call
+  kept, and runs it with no trace, lower or compile;
 * ``lpf.sync``: planning and lowering one superstep, or deferring it
   into a recording;
 * ``lpf.flush``: a recorded trace's program fetch or build,

@@ -12,6 +12,8 @@ from jax.sharding import PartitionSpec as P
 
 from repro import core as lpf
 from repro.algorithms import bsp_fft, lpf_pagerank, partition_graph, rmat_graph
+from repro.algorithms import fft as fft_mod
+from repro.algorithms import pagerank as pagerank_mod
 from repro.core import compat, span
 from repro.core import spans as spans_mod
 
@@ -93,7 +95,7 @@ def test_span_is_written_into_the_profilers_trace(tmp_path, events):
 EXEC = {"lpf.exec.trace": 1, "lpf.exec.lower": 1, "lpf.exec.compile": 1,
         "lpf.exec.run": 1}
 
-#: spans per call, by driver and number of processes
+#: spans of a driver's first call, by driver and number of processes
 PER_CALL = {
     ("fft", 1): {**EXEC, "lpf.fft.layout": 1},
     ("fft", 4): {**EXEC, "lpf.fft.layout": 1, "lpf.sync": 2,
@@ -103,6 +105,17 @@ PER_CALL = {
     ("pagerank", 4): {**EXEC, "lpf.pagerank.upload": 1, "lpf.sync": 5,
                       "lpf.flush": 3},
 }
+
+#: spans of every later call, which runs the executable exec_ kept
+WARM = {
+    "fft": {"lpf.exec.hit": 1, "lpf.exec.run": 1, "lpf.fft.layout": 1},
+    "pagerank": {"lpf.exec.hit": 1, "lpf.exec.run": 1,
+                 "lpf.pagerank.upload": 1},
+}
+
+#: each driver's factory of the SPMD function exec_ keys on
+FACTORY = {"fft": fft_mod._fft_exec_spmd,
+           "pagerank": pagerank_mod._pagerank_exec_spmd}
 
 
 def _call(driver, p):
@@ -116,15 +129,22 @@ def _call(driver, p):
     return lambda: lpf_pagerank(mesh, g)
 
 
+@pytest.mark.parametrize("warm", [False, True], ids=["cold", "warm"])
 @pytest.mark.parametrize("driver,p", sorted(PER_CALL))
-def test_driver_spans_per_call(events, driver, p):
+def test_driver_spans_per_call(events, driver, p, warm):
     call = _call(driver, p)
-    call()                                 # plans and compiles once
-    del events[:]
-    call()
-    call()
+    if warm:
+        call()                             # plans and compiles once
+        del events[:]
+        calls, expect = 2, WARM[driver]
+    else:
+        # a new SPMD function, for which exec_ has kept nothing
+        FACTORY[driver].cache_clear()
+        calls, expect = 1, PER_CALL[(driver, p)]
+    for _ in range(calls):
+        call()
     got = collections.Counter(n for n, _, _ in _spans(events))
-    assert got == {k: 2 * v for k, v in PER_CALL[(driver, p)].items()}
+    assert got == {k: calls * v for k, v in expect.items()}
     parents = {(n, par) for n, _, par in _spans(events)}
     # LPF's planning happens while exec_ traces; the rest is top level
     assert {par for n, par in parents if n in ("lpf.sync", "lpf.flush")} \
