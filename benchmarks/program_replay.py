@@ -323,106 +323,7 @@ def check_overlap_ledger_bit_for_bit(p: int = 8):
     return len(records)
 
 
-# --------------------------------------------------------------------------
-# 4. compiled replay: fused whole-program XLA vs per-call dispatch
-# --------------------------------------------------------------------------
-
-COMPILED_ITERS = 64
-COMPILED_ELEMS = 256             # small h: dispatch overhead dominates
-COMPILED_BUCKET = 128            # -> 2 buckets (4 collectives) per iter
-COMPILED_REPS = 5
-
-
-def bench_compiled_replay(p: int = 8):
-    """Per-iteration cost of a small-h bucketed-sync program, two ways:
-
-    * **dispatched** — one jitted call per iteration with whole-program
-      compilation off: every iteration pays a host-side jax dispatch
-      plus the Python per-superstep execute path inside the trace-free
-      replay (the pre-tentpole steady state);
-    * **fused** — all ``COMPILED_ITERS`` iterations rolled into ONE
-      jitted call via ``ctx.compile_loop`` (one ``lax.scan`` whose body
-      is the compiled program): one dispatch, zero per-iteration Python.
-
-    At 4 KiB payloads the work per iteration is trivial, so the ratio
-    isolates exactly the dispatch overhead the tentpole removes.
-    Returns ([(name, per_iter_us)], ratio, max_abs_err)."""
-    mesh = compat.make_mesh((p,), ("x",))
-    from repro import core as lpf
-    from repro.bsp.pod_sync import lpf_bucketed_allreduce
-
-    def one_iter(ctx, x):
-        return lpf_bucketed_allreduce(ctx, x, COMPILED_BUCKET, mean=True)
-
-    def dispatched(x):
-        ctx = lpf.LPFContext(("x",))
-        ctx.compile_programs = False
-        return one_iter(ctx, x.reshape(-1))
-
-    def fused(x):
-        ctx = lpf.LPFContext(("x",))
-        return ctx.compile_loop(one_iter, x.reshape(-1),
-                                n_iters=COMPILED_ITERS, label="ddp")
-
-    sm = lambda f: jax.jit(compat.shard_map(
-        f, mesh=mesh, in_specs=(P("x"),), out_specs=P("x"),
-        check_vma=False))
-    disp_fn, fused_fn = sm(dispatched), sm(fused)
-
-    x = (jnp.arange(p * COMPILED_ELEMS, dtype=jnp.float32)
-         % 97.0) * 0.25 + 1.0
-    jax.block_until_ready(disp_fn(x))           # compile + warm
-    jax.block_until_ready(fused_fn(x))
-
-    def run_disp():
-        # block every iteration: (a) the faithful dispatched baseline —
-        # each Python-issued step completes before the next is issued —
-        # and (b) required on oversubscribed hosts, where letting tens
-        # of async 8-way host collectives queue up can deadlock XLA's
-        # cross-module rendezvous (device threads >> cores)
-        y = x
-        for _ in range(COMPILED_ITERS):
-            y = jax.block_until_ready(disp_fn(y))
-        return y
-
-    t_disp, t_fused = [], []
-    for _ in range(COMPILED_REPS):
-        t0 = time.perf_counter()
-        y_disp = run_disp()
-        t_disp.append((time.perf_counter() - t0) / COMPILED_ITERS)
-        t0 = time.perf_counter()
-        y_fused = jax.block_until_ready(fused_fn(x))
-        t_fused.append((time.perf_counter() - t0) / COMPILED_ITERS)
-
-    # numerics: repeated mean-allreduce is idempotent after the first
-    # iteration, so both paths must land on the cross-pid mean
-    ref = np.tile(np.asarray(x).reshape(p, COMPILED_ELEMS).mean(axis=0),
-                  p)
-    err = max(np.abs(np.asarray(y_disp) - ref).max(),
-              np.abs(np.asarray(y_fused) - ref).max())
-    d_us = statistics.median(t_disp) * 1e6
-    f_us = statistics.median(t_fused) * 1e6
-    return [("dispatched", d_us), ("fused", f_us)], d_us / f_us, float(err)
-
-
-def compiled_replay_main(csv: bool = True):
-    rows, ratio, err = bench_compiled_replay()
-    assert err < 1e-4, f"fused/dispatched numerics diverged: {err}"
-    assert ratio >= 2.0, \
-        (f"fused replay must cut per-iteration dispatch overhead >= 2x "
-         f"(got {ratio:.2f}x)")
-    out = [("compiled_replay", name, COMPILED_ITERS, "", "",
-            f"{us:.1f}us/iter") for name, us in rows]
-    if csv:
-        print("bench,name,iters,_,_,per_iter")
-        for row in out:
-            print(",".join(str(x) for x in row))
-        print(f"# fused vs dispatched per-iteration speedup: {ratio:.1f}x "
-              f"(max abs err {err:.2e})")
-    return out
-
-
-def main(csv: bool = True, compiled: bool = True):
+def main(csv: bool = True):
     out = []
     b_rows = bench_bucketed()
     per_layer = next(r for r in b_rows if r[0] == "per-layer")
@@ -475,9 +376,6 @@ def main(csv: bool = True, compiled: bool = True):
 
     n_ovl = check_overlap_ledger_bit_for_bit()
     out.append(("overlap_ledger", "bit-for-bit", n_ovl, "", "", "ok"))
-
-    if compiled:
-        out += compiled_replay_main(csv=False)
 
     if csv:
         print("bench,name,supersteps_or_plans,rounds,wire_bytes,ms")

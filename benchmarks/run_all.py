@@ -48,8 +48,7 @@ def _jobs(fast: bool):
             sizes=((1 << 10, 6),) if fast
             else ((1 << 12, 6), (1 << 14, 6))),
         "roofline": lambda: roofline.main(),
-        "overlap": lambda: program_replay.main(compiled=False),
-        "compiled_replay": lambda: program_replay.compiled_replay_main(),
+        "overlap": lambda: program_replay.main(),
         "serve": lambda: serve_latency.main(
             n_requests=40 if fast else 120),
     }

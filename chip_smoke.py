@@ -26,8 +26,8 @@ against ``jnp.fft.fft`` on one device, and ``lpf_pagerank`` on 2^22
 vertices; each result must be spread over all four devices.
 
 Every phase prints one line with its wall and compile seconds.  The run
-fails if any phase fails, or if the program cache fell back from a
-compile or the server from a fused decode.  The last line of stdout is
+fails if any phase fails, or if the server fell back from a fused
+decode.  The last line of stdout is
 the JSON verdict ``{"ok": true, "device": {...}}``; without a TPU the
 script exits nonzero and prints no verdict.
 """
@@ -348,7 +348,7 @@ def main(argv=None) -> int:
         print(f"chip_smoke: no repro package at {src}", file=sys.stderr)
         return 2
     sys.path.insert(0, src)
-    from repro.core import global_program_cache, hardware_for
+    from repro.core import hardware_for
     from repro.launch.compile_cache import enable_compile_cache
     import jax
 
@@ -400,8 +400,6 @@ def main(argv=None) -> int:
         t0 = time.perf_counter()
         try:
             res = run()
-            fallbacks = global_program_cache().stats.compile_fallbacks
-            _check(fallbacks == 0, f"{fallbacks} program compile fallbacks")
             status = "ok"
         except Exception:
             traceback.print_exc()

@@ -13,7 +13,7 @@ proves the optimizer's legality invariants *statically*, on the IR:
   (topological order, commuting merges, overlap contracts, Valiant
   rewrites on conflict-free tables, cost compliance) — the certificate
   :meth:`repro.core.ProgramCache.certify` attaches to every cache entry
-  and :meth:`~repro.core.ProgramCache.set_compiled` requires;
+  and every recorded program needs before it executes;
 * :mod:`repro.analysis.traces` — the canned benchmark traces, shared
   with ``benchmarks/schedule_search.py``;
 * ``python -m repro.analysis`` — the CLI (see ``__main__.py``).

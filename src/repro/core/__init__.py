@@ -33,14 +33,13 @@ from .machine import (CPU_HOST, TPU_V5E, TPU_V5P, HardwareModel, LinkModel,
                       LPFMachine, hardware_for, probe)
 from .memslot import Slot, SlotRegistry
 from .persist import PersistentStore, PersistError, steps_from_signature
-from .program import (CompiledProgram, OptimizedStep, ProgramCache,
-                      ProgramStep, SuperstepProgram, canonical_order,
-                      compile_program, dependency_cone,
+from .program import (OptimizedStep, ProgramCache, ProgramStep,
+                      SuperstepProgram, canonical_order, dependency_cone,
                       global_program_cache, optimize_program,
                       program_signature, simulate_program, trace_slot_map)
 from .spans import span
 from .sync import (CacheStats, Msg, OVERLAPPABLE_METHODS, PlanCache,
-                   RoundPlan, SuperstepPlan, ValueStore, begin_plan,
+                   RoundPlan, SuperstepPlan, begin_plan,
                    conflict_free, execute_overlapped, execute_plan,
                    find_conflict,
                    execute_schedule, global_plan_cache, plan_cost,
@@ -66,9 +65,9 @@ __all__ = [
     "plan_sync", "plan_signature", "plan_cost", "execute_plan",
     "global_plan_cache", "compat",
     "ProgramStep", "OptimizedStep", "SuperstepProgram", "ProgramCache",
-    "CompiledProgram", "compile_program", "trace_slot_map",
+    "trace_slot_map",
     "program_signature", "optimize_program", "global_program_cache",
-    "simulate_program", "ValueStore", "execute_schedule",
+    "simulate_program", "execute_schedule",
     "PersistentStore", "PersistError", "steps_from_signature",
     "CollectiveStats", "RooflineTerms", "parse_collectives", "roofline_terms",
     "span",

@@ -71,10 +71,9 @@ def shard_map(f: Callable, *, mesh: Any, in_specs: Any, out_specs: Any,
 
 # -- structured control flow -----------------------------------------------
 #
-# ``lax.scan``/``lax.while_loop`` are the symbols whole-program
-# compilation (compiled SuperstepProgram replay, ``LPFContext
-# .compile_loop``, the fused decode loop) hangs off — routed through here
-# so a future signature change has one place to land.
+# ``lax.scan``/``lax.while_loop`` are the symbols ``LPFContext
+# .compile_loop`` and the fused decode loop hang off — routed through
+# here so a future signature change has one place to land.
 
 def scan(f, init, xs, length=None):
     """``lax.scan`` (body traced once; per-iteration work compiles into

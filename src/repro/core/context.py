@@ -36,13 +36,11 @@ from . import compat
 from . import faultpoints as _fp
 from .attrs import LPF_SYNC_DEFAULT, SyncAttributes
 from .cost import CostLedger, SuperstepCost
-from .errors import (LPFAnalysisError, LPFCapacityError, LPFError,
-                     LPFFatalError)
+from .errors import LPFAnalysisError, LPFCapacityError, LPFFatalError
 from .machine import LPFMachine, HardwareModel, TPU_V5E, probe as _probe
 from .memslot import Slot, SlotRegistry
-from .program import (ProgramCache, ProgramStep, compile_program,
-                      dependency_cone, global_program_cache,
-                      trace_slot_map)
+from .program import (ProgramCache, ProgramStep, dependency_cone,
+                      global_program_cache)
 from .spans import span
 from .sync import (Msg, PlanCache, execute_plan, execute_schedule,
                    global_plan_cache)
@@ -109,7 +107,7 @@ class LPFContext:
         #: directory — a restarted worker pays zero re-planning and
         #: zero schedule-search cost.  Loaded entries are re-verified
         #: (``verify_program``) against the actual recorded trace
-        #: before they may execute or compile.
+        #: before they may execute.
         if persist_dir is None and _parent is None:
             persist_dir = os.environ.get("LPF_PROGRAM_CACHE_DIR") or None
         if persist_dir:
@@ -126,13 +124,6 @@ class LPFContext:
         self._rec_pending: List[ProgramStep] = []
         self._rec_deferred_dereg: List[Slot] = []
         self._gate_machine: Optional[LPFMachine] = None
-        #: lower optimized programs into single jitted XLA computations
-        #: (:class:`repro.core.program.CompiledProgram`) instead of
-        #: Python-dispatched superstep-by-superstep replay; the ledger is
-        #: identical either way (``SuperstepProgram.ledger_costs``).  Set
-        #: ``LPF_COMPILE_PROGRAMS=0`` to force the dispatched path.
-        self.compile_programs: bool = \
-            os.environ.get("LPF_COMPILE_PROGRAMS", "1") != "0"
         #: the most recently executed (optimized) program — inspect the
         #: searched schedule with ``ctx.last_program.explain(machine)``
         self.last_program = None
@@ -501,16 +492,7 @@ class LPFContext:
         supersteps (non-adjacent hoists); ``materialize`` resolves the
         program's canonical ranks against this trace's own canonical
         order, so labels and staged-message reuse stay attached to the
-        right recorded steps whatever order the scheduler emitted.
-
-        With :attr:`compile_programs` (the default) the whole schedule
-        runs as ONE jitted computation: slot values flow in, the
-        compiled body issues every superstep, results write back through
-        the registry's validating ``set_value``.  The dispatched path
-        below it executes the same plans through the same
-        ``execute_schedule`` loop, so the two ledgers are bit-for-bit
-        identical — ``ledger_costs`` and ``execute_schedule`` both read
-        the plans' predicted costs."""
+        right recorded steps whatever order the scheduler emitted."""
         from .program import canonical_order
         order = canonical_order(steps)
         prog, key = self.program_cache.get_or_build_keyed(
@@ -518,8 +500,8 @@ class LPFContext:
             scratch=self._scratch, order=order)
         self.last_program = prog
         # every schedule is certified (memoized per cache key) before it
-        # may execute or be compiled; a program the verifier cannot
-        # certify never reaches the wire
+        # may execute; a program the verifier cannot certify never
+        # reaches the wire
         cert = self.program_cache.certify(key, steps, prog,
                                           scratch=self._scratch,
                                           order=order)
@@ -536,45 +518,10 @@ class LPFContext:
         if d > 0:
             time.sleep(d)
         labels = [st.label for st in steps]
-        cp = None
-        if self.compile_programs and \
-                not self.program_cache.compile_quarantined(key, self.axes):
-            cp = self.program_cache.compiled(key, self.axes)
-            if cp is None:
-                # graceful degradation: a *foreign* compilation failure
-                # (XLA, OOM, injected) falls back to the dispatched
-                # execute_schedule path below — the SAME certified
-                # program, so numerics and ledger are bit-for-bit
-                # identical — and quarantines this (key, axes) so
-                # replays skip the doomed compile.  LPF errors are
-                # contract violations, never degraded around.
-                try:
-                    cp = compile_program(prog, steps, order, self.p,
-                                         self.axes,
-                                         scratch=self._scratch)
-                except LPFError:
-                    raise
-                except Exception as e:
-                    self.program_cache.quarantine_compile(
-                        key, self.axes, e)
-                else:
-                    self.program_cache.set_compiled(key, self.axes, cp)
-        if cp is not None:
-            slots = trace_slot_map(steps, order)
-            vals = [self.registry.value(s) for s in slots]
-            scratch_val = self.registry.value(self._scratch) \
-                if cp.scratch is not None else None
-            out_vals, out_scratch = cp(self.pid, vals, scratch_val)
-            for s, v in zip(slots, out_vals):
-                self.registry.set_value(s, v)
-            if cp.scratch is not None:
-                self.registry.set_value(self._scratch, out_scratch)
-            costs = prog.ledger_costs(labels, order)
-        else:
-            entries = prog.materialize(steps, labels, order=order)
-            costs = execute_schedule(entries, prog.groups(),
-                                     self.registry, self.p, self.axes,
-                                     self.pid, scratch=self._scratch)
+        entries = prog.materialize(steps, labels, order=order)
+        costs = execute_schedule(entries, prog.groups(), self.registry,
+                                 self.p, self.axes, self.pid,
+                                 scratch=self._scratch)
         for cost in costs:
             self.ledger.add(cost)
 
@@ -652,13 +599,12 @@ class LPFContext:
 
         ``body(sub_ctx, carry) -> carry`` runs each iteration's compute
         and supersteps against a fresh sub-context whose trace records
-        as one program (so the schedule search and the compiled-program
-        path apply per iteration); the loop itself lowers through
-        ``compat.scan`` (``n_iters``) or ``compat.while_loop``
-        (``cond(carry) -> bool``), so N iterations issue as a single
-        XLA ``While`` computation instead of N Python-dispatched calls —
-        the torch_xla ``fori_loop`` pattern.  Exactly one of
-        ``n_iters``/``cond`` must be given.
+        as one program (so the schedule search applies per iteration);
+        the loop itself lowers through ``compat.scan`` (``n_iters``) or
+        ``compat.while_loop`` (``cond(carry) -> bool``), so N iterations
+        issue as a single XLA ``While`` computation instead of N
+        Python-dispatched calls — the torch_xla ``fori_loop`` pattern.
+        Exactly one of ``n_iters``/``cond`` must be given.
 
         The body traces ONCE: its per-iteration superstep costs are
         appended to this context's ledger once (the BSP model prices one
@@ -683,7 +629,6 @@ class LPFContext:
                              plan_cache=self.plan_cache,
                              program_cache=self.program_cache,
                              _parent=self)
-            sub.compile_programs = self.compile_programs
             ledgers.append(sub.ledger)
             with sub.program(label):
                 out = body(sub, c)
@@ -826,8 +771,7 @@ def _exec_key(spmd: Callable, mesh: jax.sharding.Mesh,
     leaves, tree = jax.tree.flatten(args)
     # what JAX and LPFContext read from the process while tracing
     env = (jax.config.jax_enable_x64,
-           os.environ.get("LPF_SANITIZE", "0") != "0",
-           os.environ.get("LPF_COMPILE_PROGRAMS", "1") != "0")
+           os.environ.get("LPF_SANITIZE", "0") != "0")
     key = (spmd, mesh, axes, _spec_key(in_specs), _spec_key(out_specs),
            hardware, tree, tuple(_leaf_key(x) for x in leaves), env)
     try:

@@ -17,9 +17,6 @@ Seams (see ``runtime/faults.py`` for the plan grammar):
 ``persist_load``
     :meth:`repro.core.persist.PersistentStore._read` — injected
     ``OSError`` or a truncated / bit-flipped blob.
-``compile``
-    :func:`repro.core.program.compile_program` — injected XLA
-    compilation failure (:class:`InjectedFault`).
 ``straggler``
     :meth:`repro.core.context.LPFContext._execute_steps` — wall-clock
     delay before the schedule issues (straggler simulation).
@@ -45,8 +42,8 @@ from typing import Optional
 __all__ = ["InjectedFault", "SEAMS", "armed", "fire", "corrupt", "delay"]
 
 #: the closed set of seam names a plan may target
-SEAMS = ("persist_save", "persist_load", "compile", "straggler",
-         "capacity", "serve_admit", "serve_decode")
+SEAMS = ("persist_save", "persist_load", "straggler", "capacity",
+         "serve_admit", "serve_decode")
 
 
 class InjectedFault(RuntimeError):

@@ -32,7 +32,7 @@ Trust model — a loaded entry is *advisory*, never authoritative:
   renamed-file defence);
 * and above all, :class:`repro.core.program.ProgramCache` re-runs
   ``verify_program`` on every loaded entry against the *actual*
-  recorded trace before the program may execute or compile — a stale or
+  recorded trace before the program may execute — a stale or
   adversarial entry can cost a re-optimization, never a wrong schedule.
 
 :func:`steps_from_signature` reconstructs a synthetic recorded trace

@@ -99,21 +99,19 @@ from typing import (Any, Callable, Dict, Hashable, List, Optional, Sequence,
 
 import numpy as np
 
-from . import faultpoints as _fp
 from .attrs import SyncAttributes
 from .cost import SuperstepCost, overlap_cost, schedule_seconds
-from .errors import LPFAnalysisError, LPFFatalError
+from .errors import LPFFatalError
 from .machine import LPFMachine
 from .memslot import Slot
 from .sync import (CacheStats, Msg, OVERLAPPABLE_METHODS, PlanCache,
-                   SuperstepPlan, ValueStore, conflict_free,
-                   execute_schedule, plan_sync)
+                   SuperstepPlan, conflict_free, plan_sync)
 
 __all__ = [
     "ProgramStep", "OptimizedStep", "SuperstepProgram", "ProgramCache",
-    "CompiledProgram", "compile_program", "global_program_cache",
-    "program_signature", "optimize_program", "simulate_program",
-    "dependency_cone", "canonical_order", "trace_slot_map",
+    "global_program_cache", "program_signature", "optimize_program",
+    "simulate_program", "dependency_cone", "canonical_order",
+    "trace_slot_map",
 ]
 
 #: combined planned rounds at which the scheduler bothers pricing a
@@ -343,9 +341,8 @@ class SuperstepProgram:
         — precisely what :func:`repro.core.sync.execute_schedule`
         returns.  Labels resolve the way :meth:`materialize` resolves
         them (``labels`` in recorded order, ``merged_from`` ranks mapped
-        through ``order``), so the compiled whole-program path — which
-        cannot thread cost records through a jitted body — ledgers
-        bit-for-bit what the step-by-step path would."""
+        through ``order``), so the schedule verifier prices the
+        program bit-for-bit as its execution ledgers it."""
         out: List[SuperstepCost] = []
         for grp in self.groups():
             lbls = []
@@ -1267,103 +1264,6 @@ def optimize_program(steps: Sequence[ProgramStep], p: int,
 
 
 # ==========================================================================
-# whole-program compilation
-# ==========================================================================
-
-@dataclasses.dataclass
-class CompiledProgram:
-    """An optimized program lowered into ONE jitted function.
-
-    Step-by-step replay pays a Python dispatch (plan lookup, executor
-    re-trace under the outer jit, per-superstep bookkeeping) per issue
-    group; for small-h programs that overhead dominates the modelled
-    cost.  Following the torch_xla ``fori_loop`` / pMR persistent-
-    communication-object pattern, the whole schedule — every superstep
-    *and* the canonical dataflow between them — is traced once against a
-    :class:`repro.core.sync.ValueStore` over canonical slots and jitted;
-    replays feed the actual slot values in and write the results back.
-
-    Validity is anchored to the program signature: the canonical tables
-    name slots by canonical index, the signature pins every index's
-    (size, dtype, kind) descriptor and the scratch descriptor, so any
-    trace that maps to this cache key can run through this function.
-    The ledger is NOT produced inside the jitted body (cost records are
-    static Python); callers append
-    :meth:`SuperstepProgram.ledger_costs`, which is by construction
-    identical to what step-by-step execution returns."""
-
-    prog: SuperstepProgram
-    slots: Tuple[Slot, ...]          # canonical slots, sid == index
-    scratch: Optional[Slot]          # canonical scratch (valiant), or None
-    fn: Callable = dataclasses.field(repr=False, default=None)
-    n_calls: int = 0
-
-    def __call__(self, myid, values, scratch_val=None):
-        self.n_calls += 1
-        if self.scratch is not None:
-            return self.fn(myid, tuple(values), scratch_val)
-        return self.fn(myid, tuple(values)), scratch_val
-
-
-def compile_program(prog: SuperstepProgram, steps: Sequence[ProgramStep],
-                    order: Sequence[int], p: int,
-                    axes: Tuple[str, ...],
-                    scratch: Optional[Slot] = None) -> CompiledProgram:
-    """Lower ``prog`` into a :class:`CompiledProgram` for ``(p, axes)``.
-
-    ``steps``/``order`` are any trace/canonical-order pair matching the
-    program's signature — only their slot *descriptors* are consulted
-    (to synthesize the canonical slot list), so the compiled function is
-    reusable by every trace that hits the same cache entry."""
-    import jax
-
-    # fault seam: an armed plan may stand in for an XLA compilation
-    # failure here; callers degrade to the dispatched schedule
-    _fp.fire("compile", label=getattr(prog, "label", ""))
-
-    actual = trace_slot_map(steps, order)
-    slots = tuple(Slot(i, f"__prog_slot{i}", s.size, s.dtype, s.kind,
-                       (s.size,))
-                  for i, s in enumerate(actual))
-    # valiant phase-1 bounces through the scratch slot; sid -1 cannot
-    # collide with a canonical index
-    need_scratch = any(st.plan.method == "valiant" for st in prog.steps)
-    if need_scratch and scratch is None:
-        raise LPFFatalError("program contains valiant supersteps but the "
-                            "context has no scratch slot")
-    cscratch = Slot(-1, "__prog_scratch", scratch.size, scratch.dtype,
-                    "global", (scratch.size,)) if need_scratch else None
-
-    entries = []
-    for st in prog.steps:
-        # rebuild from the canonical table unconditionally (an
-        # ``unchanged`` step's table IS its staged messages modulo the
-        # slot renaming, and the compiled body must speak canonical sids)
-        msgs = [Msg(src, dst, slots[si], so, slots[di], do, sz,
-                    origin=origin)
-                for (src, dst, si, so, di, do, sz, origin) in st.table]
-        entries.append((msgs, st.attrs, st.label, st.plan))
-    groups = prog.groups()
-
-    if need_scratch:
-        def run(myid, vals, scratch_val):
-            store = ValueStore({s.sid: v for s, v in zip(slots, vals)})
-            store.set_value(cscratch, scratch_val)
-            execute_schedule(entries, groups, store, p, axes, myid,
-                             scratch=cscratch)
-            return (tuple(store.value(s) for s in slots),
-                    store.value(cscratch))
-    else:
-        def run(myid, vals):
-            store = ValueStore({s.sid: v for s, v in zip(slots, vals)})
-            execute_schedule(entries, groups, store, p, axes, myid)
-            return tuple(store.value(s) for s in slots)
-
-    return CompiledProgram(prog=prog, slots=slots, scratch=cscratch,
-                           fn=jax.jit(run))
-
-
-# ==========================================================================
 # the program cache
 # ==========================================================================
 
@@ -1397,14 +1297,9 @@ class ProgramCache:
         self.maxsize = maxsize
         self._programs: "collections.OrderedDict[Hashable, SuperstepProgram]" \
             = collections.OrderedDict()
-        #: program key -> {axes tuple: CompiledProgram}; a compiled
-        #: artifact is only valid alongside its program entry, so
-        #: eviction drops both (LRU coherence)
-        self._compiled: Dict[Hashable, Dict[Tuple[str, ...],
-                                            "CompiledProgram"]] = {}
         #: program key -> schedule-verifier certificate
-        #: (:class:`repro.analysis.VerifierReport`); ``set_compiled``
-        #: refuses keys without a passing one
+        #: (:class:`repro.analysis.VerifierReport`); a program executes
+        #: only with a passing one
         self._certs: Dict[Hashable, Any] = {}
         self.stats = CacheStats()
         self._store = None
@@ -1416,10 +1311,6 @@ class ProgramCache:
         #: memory so a corrupt-but-undeletable file costs ONE decode +
         #: verify, not one per miss
         self._poisoned: set = set()
-        #: (key, axes) pairs whose whole-program compilation failed:
-        #: replays go straight to the dispatched path instead of
-        #: re-paying a doomed XLA compile every flush
-        self._quarantined: Dict[Hashable, set] = {}
         #: keys exempt from LRU eviction (:meth:`pin`) — the serving
         #: path pins its hot decode-bucket programs so a burst of cold
         #: one-shot signatures can never evict the entries every
@@ -1502,11 +1393,9 @@ class ProgramCache:
         counters).  On-disk entries are untouched — a cleared cache
         warm-starts from its store, which is the point of having one."""
         self._programs.clear()
-        self._compiled.clear()
         self._certs.clear()
         self._persisted = set()
         self._poisoned = set()
-        self._quarantined = {}
         self._pinned = set()
         self._disk_strikes = 0
         self.stats = CacheStats()
@@ -1548,30 +1437,6 @@ class ProgramCache:
             return
         self._write_back(key, prog, cert)
 
-    def compiled(self, key: Hashable,
-                 axes: Sequence[str]) -> Optional["CompiledProgram"]:
-        """The compiled form of the cached program under ``key`` for an
-        axes tuple, if one has been built (compilation is per-axes: the
-        jitted body bakes in the collective axis names)."""
-        return self._compiled.get(key, {}).get(tuple(axes))
-
-    def set_compiled(self, key: Hashable, axes: Sequence[str],
-                     cp: "CompiledProgram") -> None:
-        if key not in self._programs:
-            raise LPFFatalError(
-                "set_compiled for a key with no cached program")
-        cert = self._certs.get(key)
-        if cert is None:
-            raise LPFAnalysisError(
-                "set_compiled for an uncertified program: call "
-                "ProgramCache.certify(key, steps) first — compiled "
-                "artifacts are only cached for verified schedules")
-        if not cert.ok:
-            raise LPFAnalysisError(
-                "set_compiled for a program whose schedule failed "
-                f"verification: {cert.summary()}")
-        self._compiled.setdefault(key, {})[tuple(axes)] = cp
-
     def certify(self, key: Hashable, steps: Sequence[ProgramStep],
                 prog: Optional[SuperstepProgram] = None,
                 scratch: Optional[Slot] = None,
@@ -1580,7 +1445,7 @@ class ProgramCache:
         ``key`` against its recorded trace and memoize the resulting
         :class:`repro.analysis.VerifierReport`.  ``scratch``/``order``
         must match what :meth:`get_or_build_keyed` optimized with.
-        Idempotent per key; :meth:`set_compiled` requires a passing
+        Idempotent per key; a program executes only with a passing
         certificate."""
         cert = self._certs.get(key)
         if cert is not None:
@@ -1619,8 +1484,7 @@ class ProgramCache:
                            order: Optional[Sequence[int]] = None
                            ) -> Tuple[SuperstepProgram, Hashable]:
         """Like :meth:`get_or_build` but also returns the cache key, the
-        handle :meth:`compiled`/:meth:`set_compiled` attach the jitted
-        whole-program artifact to."""
+        handle :meth:`certify` memoizes the certificate under."""
         # the machine's (g, l) keys the cache too: the cost gates price
         # rewrites with them, so contexts over different link classes
         # must not share optimization decisions
@@ -1754,23 +1618,6 @@ class ProgramCache:
             self._maybe_persist(key)
         return len(self._persisted) - before
 
-    # -- compile quarantine ---------------------------------------------
-    def quarantine_compile(self, key: Hashable, axes: Sequence[str],
-                           err: Optional[BaseException] = None) -> None:
-        """Record that whole-program compilation of ``key`` for an axes
-        tuple failed: replays fall back to the dispatched
-        ``execute_schedule`` path (same certified program, identical
-        ledger) instead of re-paying a doomed XLA compile every flush.
-        Counted in ``stats.compile_fallbacks``."""
-        self._quarantined.setdefault(key, set()).add(tuple(axes))
-        self.stats.compile_fallbacks += 1
-
-    def compile_quarantined(self, key: Hashable,
-                            axes: Sequence[str]) -> bool:
-        """Has compilation of ``key`` for this axes tuple been
-        quarantined by a prior failure?"""
-        return tuple(axes) in self._quarantined.get(key, ())
-
     def _insert(self, key: Hashable, prog: SuperstepProgram) -> None:
         self._programs[key] = prog
         # maxsize bounds the UNPINNED population: eviction picks the
@@ -1786,8 +1633,6 @@ class ProgramCache:
             return
         eprog = self._programs.pop(evicted)
         cert = self._certs.pop(evicted, None)
-        self._compiled.pop(evicted, None)
-        self._quarantined.pop(evicted, None)
         self.stats.evictions += 1
         # write-back on evict: an entry leaving memory keeps its
         # disk copy (or gains one) so the next process — or the

@@ -80,7 +80,7 @@ __all__ = [
     "plan_sync", "plan_signature", "begin_plan", "execute_plan",
     "execute_overlapped", "execute_schedule", "execute_sync", "plan_cost",
     "conflict_free", "find_conflict", "global_plan_cache",
-    "OVERLAPPABLE_METHODS", "ValueStore",
+    "OVERLAPPABLE_METHODS",
 ]
 
 AxisNames = Tuple[str, ...]
@@ -795,11 +795,6 @@ class CacheStats:
     #: ``ProgramCache.DISK_STRIKE_LIMIT`` consecutive failures the
     #: cache detaches its store and runs memory-only.
     disk_errors: int = 0
-    #: whole-program compilations that failed and fell back to the
-    #: dispatched ``execute_schedule`` path (same certified program,
-    #: ledger bit-for-bit); the failing signature is quarantined so
-    #: replays skip the doomed compile
-    compile_fallbacks: int = 0
 
     @property
     def plans(self) -> int:
@@ -812,7 +807,7 @@ class CacheStats:
         process restart or a cold cache."""
         self.hits = self.misses = self.evictions = 0
         self.disk_hits = self.disk_misses = self.invalidated = 0
-        self.disk_errors = self.compile_fallbacks = 0
+        self.disk_errors = 0
 
 
 class PlanCache:
@@ -1350,39 +1345,17 @@ def execute_overlapped(items: Sequence[Tuple[SuperstepPlan, Sequence[Msg],
                         label="||".join(label for _, _, _, label in items))
 
 
-class ValueStore:
-    """The minimal slot-value surface the executors consume — a
-    duck-type of :class:`repro.core.memslot.SlotRegistry` holding only
-    ``sid -> value``.  Every ``begin_plan`` lowering touches a registry
-    exclusively through ``value``/``set_value``, which is what lets a
-    whole optimized program run against this store inside one jitted
-    function (``repro.core.program.CompiledProgram``): values enter as
-    jit arguments, flow through the schedule as tracers, and leave as
-    jit outputs.  No registration or capacity checks — the real registry
-    re-validates shapes/dtypes when the results are written back."""
-
-    def __init__(self, values: Dict[int, jnp.ndarray]):
-        self._values = dict(values)
-
-    def value(self, slot: Slot) -> jnp.ndarray:
-        return self._values[slot.sid]
-
-    def set_value(self, slot: Slot, value: jnp.ndarray) -> None:
-        self._values[slot.sid] = value
-
-
 def execute_schedule(entries, groups, registry, p: int, axes: AxisNames,
                      myid, scratch: Optional[Slot] = None
                      ) -> List[SuperstepCost]:
     """Issue one optimized program's schedule: ``entries`` are the
     materialized ``(msgs, attrs, label, plan)`` supersteps and ``groups``
     the issue partition (singletons via :func:`execute_plan`, overlap
-    groups via :func:`execute_overlapped`).  The single executor loop
-    shared by step-by-step replay and the compiled whole-program path —
-    both produce the returned ledger entries from the same plans, which
-    is what makes the fused ledger bit-for-bit identical to the
-    dispatched one.  ``registry`` may be a :class:`SlotRegistry` or a
-    :class:`ValueStore`."""
+    groups via :func:`execute_overlapped`).  Its one caller is
+    ``LPFContext``'s flush of a recorded program; the returned ledger
+    entries are the plans' predicted costs, as
+    :meth:`repro.core.program.SuperstepProgram.ledger_costs` lists
+    them."""
     costs: List[SuperstepCost] = []
     for grp in groups:
         if len(grp) == 1:

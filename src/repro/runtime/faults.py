@@ -14,8 +14,6 @@ seam                      injected failure
                           (full disk / read-only cache dir)
 ``persist_load``          ``OSError``, truncated, or bit-flipped read out
                           of ``PersistentStore._read``
-``compile``               XLA compilation failure out of
-                          ``compile_program`` (:class:`InjectedFault`)
 ``straggler``             wall-clock delay before a schedule issues
 ``capacity``              mitigable ``LPFCapacityError`` at staging time
 ``serve_admit``           :class:`InjectedFault` during request admission
@@ -31,7 +29,7 @@ machinery in the tree.
 
 Plan grammar (``FaultPlan.parse`` / ``.spec()`` round-trip)::
 
-    LPF_FAULT_PLAN="compile@0;persist_load@1:bitflip;straggler@2=0.05"
+    LPF_FAULT_PLAN="capacity@0;persist_load@1:bitflip;straggler@2=0.05"
 
     event   := seam "@" at ["x" repeat] [":" mode] ["=" arg]
     at      := 0-based invocation index of the seam at which to fire
@@ -87,7 +85,6 @@ DEFAULT_DELAY = 0.02
 _MODES = {
     "persist_save": ("",),
     "persist_load": ("oserror", "truncate", "bitflip"),
-    "compile": ("",),
     "straggler": ("",),
     "capacity": ("",),
     "serve_admit": ("",),
@@ -223,8 +220,6 @@ class FaultInjector:
             return
         if seam == "persist_save":
             raise OSError(errno.ENOSPC, "injected fault: disk full")
-        if seam == "compile":
-            raise InjectedFault("injected fault: XLA compilation failed")
         if seam == "capacity":
             from ..core.errors import LPFCapacityError
             staged = int(info.get("staged", 0))
@@ -404,10 +399,9 @@ def _run_mesh_trace(steps, slots, *, use_with_capacity: bool = True):
 
 
 def _wl_bucketed_sync() -> dict:
-    """The DDP bucketed gradient sync shape on the host mesh: the
-    compile seam exercises the compiled→dispatched fallback (ledger
-    must stay bit-for-bit), capacity exercises resize-and-retry, the
-    straggler seam only costs wall clock."""
+    """The DDP bucketed gradient sync shape on the host mesh: capacity
+    exercises resize-and-retry, the straggler seam only costs wall
+    clock."""
     import jax
     from ..analysis.traces import canned_bucketed_trace
     p, slots, steps, _scratch = canned_bucketed_trace(
@@ -460,8 +454,8 @@ def _wl_serve() -> dict:
     """The hardened serve loop under fault-plus-overload: a burst
     arrival pattern into a small bounded queue (driving the ladder
     through shrink, shed, and backpressure) while the ``serve_admit``
-    and ``serve_decode`` seams (plus the program layer's ``compile`` /
-    ``straggler``) fire.  The result carries every request's terminal
+    and ``serve_decode`` seams (plus the program layer's ``straggler``)
+    fire.  The result carries every request's terminal
     state AND the per-request solo-decode reference streams; the
     invariant is per request (:func:`_serve_compare`), because under
     faults a *different* admission mix is legitimate — what is never
@@ -539,11 +533,9 @@ def _serve_compare(res: dict, baseline: dict) -> Tuple[bool, str]:
 #: workload name -> (fn, seams random plans may draw from)
 WORKLOADS = {
     "warm_start": (_wl_warm_start, ("persist_save", "persist_load")),
-    "bucketed_sync": (_wl_bucketed_sync,
-                      ("compile", "straggler", "capacity")),
-    "decode": (_wl_decode, ("compile", "straggler", "capacity")),
-    "serve": (_wl_serve, ("serve_admit", "serve_decode", "compile",
-                          "straggler")),
+    "bucketed_sync": (_wl_bucketed_sync, ("straggler", "capacity")),
+    "decode": (_wl_decode, ("straggler", "capacity")),
+    "serve": (_wl_serve, ("serve_admit", "serve_decode", "straggler")),
 }
 
 #: workloads whose pass criterion is not whole-result equality; the
@@ -560,10 +552,8 @@ SMOKE_PLANS = (
     ("warm_start", "persist_load@0:oserror"),
     ("warm_start", "persist_load@0:truncate"),
     ("warm_start", "persist_load@0:bitflip"),
-    ("bucketed_sync", "compile@0"),
     ("bucketed_sync", "straggler@0=0.005"),
     ("bucketed_sync", "capacity@0"),
-    ("decode", "compile@0"),
     ("decode", "capacity@0"),
     ("serve", "serve_admit@0"),
     ("serve", "serve_admit@0x-1"),
@@ -571,7 +561,6 @@ SMOKE_PLANS = (
     # both the fused attempt and the per-token retry fail: the whole
     # ladder runs and every affected request must end classified
     ("serve", "serve_decode@0x-1"),
-    ("serve", "compile@0x-1"),
 )
 
 

@@ -35,16 +35,14 @@ def cache_metrics(ctx) -> Dict[str, int]:
     directory is stale or corrupt and is being re-built.
 
     Beyond the per-layer :class:`~repro.core.sync.CacheStats` fields
-    (which already carry the degradation counters ``disk_errors`` and
-    ``compile_fallbacks``), the program layer exports its ladder state:
-    ``program_memory_only`` (1 = the persistent store was detached
-    after repeated I/O failures — ``ProgramCache.memory_only_reason``
-    holds the why), ``program_quarantined`` (signatures whose
-    whole-program compile failed; replays run dispatched), ``program_
-    pinned`` (eviction-exempt serving hot set) and ``program_entries``
-    (resident programs).  A health snapshot built from this dict sees
-    every rung of PR 9's degradation ladder without reaching into
-    cache internals.
+    (which already carry the degradation counter ``disk_errors``), the
+    program layer exports its ladder state: ``program_memory_only`` (1 =
+    the persistent store was detached after repeated I/O failures —
+    ``ProgramCache.memory_only_reason`` holds the why),
+    ``program_pinned`` (eviction-exempt serving hot set) and
+    ``program_entries`` (resident programs).  A health snapshot built
+    from this dict sees every rung of PR 9's degradation ladder without
+    reaching into cache internals.
     """
     out: Dict[str, int] = {}
     for layer, stats in sorted(ctx.cache_stats.items()):
@@ -54,8 +52,6 @@ def cache_metrics(ctx) -> Dict[str, int]:
     if pc is not None:
         out["program_entries"] = len(pc)
         out["program_memory_only"] = int(pc.memory_only_reason is not None)
-        out["program_quarantined"] = sum(
-            len(axes) for axes in pc._quarantined.values())
         out["program_pinned"] = len(pc.pinned)
     return out
 

@@ -12,8 +12,9 @@ Four layers:
    raises :class:`LPFAnalysisError` on error diagnostics before any
    communication and accumulates warnings on ``ctx.diagnostics``; slot
    generations catch stale handles after deregister-then-reuse;
-4. ``ProgramCache.set_compiled`` refuses uncertified (or failed)
-   entries, and ``explain`` renders the certificate summary.
+4. a recorded program executes only under a passing certificate
+   (memoized per ``ProgramCache`` key), and ``explain`` renders the
+   certificate summary.
 """
 
 import dataclasses
@@ -440,7 +441,6 @@ def test_sanitize_warnings_accumulate_on_diagnostics():
 
 def test_sanitize_recorded_trace_and_leak_warning():
     ctx = _eager_ctx(sanitize=True)
-    ctx.compile_programs = False
     a = ctx.register_global("a", jnp.arange(8, dtype=jnp.int32))
     with ctx.program("loop"):
         b = ctx.register_global("b", jnp.zeros(8, jnp.int32))
@@ -464,20 +464,35 @@ def test_sanitize_env_default(monkeypatch):
 # ---------------------------------------------------------------------------
 
 def test_set_compiled_requires_passing_certificate():
+    """A recorded program executes only under a passing certificate:
+    the first flush certifies it, a replay reuses the memoized
+    certificate, and a failed one refuses execution with
+    :class:`LPFAnalysisError` before any slot is written."""
     cache = ProgramCache(maxsize=4)
-    trace = [W, R]
-    prog, key = cache.get_or_build_keyed(trace, 2, MACHINE)
-    with pytest.raises(LPFAnalysisError, match="uncertified"):
-        cache.set_compiled(key, ("x",), object())
-    cert = cache.certify(key, trace)
-    assert cert.ok and cache.certificate(key) is cert
-    assert cache.certify(key, trace) is cert         # memoized
-    cache.set_compiled(key, ("x",), object())        # now admitted
-    assert cache.compiled(key, ("x",)) is not None
-    # a failed certificate refuses compiled artifacts outright
+    ctx = LPFContext((), program_cache=cache)
+    ctx.resize_memory_register(2)
+    ctx.resize_message_queue(4)
+    a = ctx.register_global("a", jnp.arange(8, dtype=jnp.int32))
+    b = ctx.register_global("b", jnp.zeros(8, jnp.int32))
+
+    def copy(shift):
+        ctx.write(a, jnp.arange(8, dtype=jnp.int32) + shift)
+        with ctx.program("copy"):
+            ctx.put_msgs([(0, 0, a, 0, b, 0, 8)])
+            ctx.sync()
+        return np.asarray(ctx.registry.value(b))
+
+    assert (copy(0) == np.arange(8)).all()
+    (key,) = cache.keys()
+    cert = cache.certificate(key)
+    assert cert is not None and cert.ok
+    assert (copy(100) == np.arange(8) + 100).all()
+    assert cache.certificate(key) is cert            # memoized
+    assert cache.stats.hits == 1
     cache._certs[key] = dataclasses.replace(cert, ok=False)
-    with pytest.raises(LPFAnalysisError, match="failed verification"):
-        cache.set_compiled(key, ("x",), object())
+    with pytest.raises(LPFAnalysisError, match="verification failed"):
+        copy(200)
+    assert (np.asarray(ctx.registry.value(b)) == np.arange(8) + 100).all()
     cache.clear()
     assert not cache._certs
 

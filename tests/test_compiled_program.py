@@ -1,11 +1,10 @@
-"""Whole-program compilation: fused replay vs step-by-step dispatch.
+"""Recorded programs on the mesh, and ``compile_loop``.
 
-The tentpole invariant: compiling an optimized ``SuperstepProgram`` into
-one jitted XLA computation changes *nothing observable* — slot values
-are bit-identical to per-superstep dispatch (and to the numpy
-differential oracle), and the ledger records the exact same
-``SuperstepCost`` entries (model compliance survives fusion).  Plus the
-``compile_loop`` surface: counted/conditional iterated programs rolled
+A recorded ``SuperstepProgram`` runs through ``execute_schedule`` inside
+the caller's trace: slot values equal the numpy differential oracle's,
+the ledger records exactly the program's planned costs
+(``SuperstepProgram.ledger_costs``), and a replay reuses the cached
+program.  ``compile_loop`` rolls counted/conditional iterated programs
 into one ``lax.scan``/``while_loop``.
 """
 
@@ -20,7 +19,8 @@ from jax.sharding import PartitionSpec as P  # noqa: E402
 
 from repro import core as lpf  # noqa: E402
 from repro.core import (LPF_SYNC_DEFAULT, Msg, ProgramStep, Slot,  # noqa: E402
-                        SyncAttributes, compat, simulate_program)
+                        SyncAttributes, canonical_order, compat,
+                        simulate_program)
 
 P_MESH = 8
 
@@ -101,21 +101,14 @@ def _init_np(slots, p):
         for pid in range(p)]).astype(np.int32) for s in slots}
 
 
-def _run_trace_on_mesh(mesh8, slots, steps, *, compiled,
-                       plan_cache=None, program_cache=None):
+def _run_trace_on_mesh(mesh8, slots, steps):
     """Issue a canned ProgramStep trace through the real ``ctx.program``
     path; returns ({sid: [p, size] np.ndarray}, ledger records, ctx)."""
-    # NOT `plan_cache or ...`: both caches define __len__, so an EMPTY
-    # cache passed by a test is falsy and would be silently replaced
-    pc = plan_cache if plan_cache is not None else lpf.PlanCache()
-    pgc = program_cache if program_cache is not None \
-        else lpf.ProgramCache()
+    pc, pgc = lpf.PlanCache(), lpf.ProgramCache()
     box = {}
 
     def wrapped(_):
         ctx = lpf.LPFContext(("x",), plan_cache=pc, program_cache=pgc)
-        if compiled is not None:   # None: leave the env default in charge
-            ctx.compile_programs = compiled
         ctx.resize_memory_register(len(slots) + 1)
         ctx.resize_message_queue(max(len(st.msgs) for st in steps))
         smap = {}
@@ -143,30 +136,28 @@ def _run_trace_on_mesh(mesh8, slots, steps, *, compiled,
 
 
 # ---------------------------------------------------------------------------
-# fused == dispatched == oracle, values AND ledger
+# program == oracle, values AND ledger
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("name", sorted(CANNED))
-def test_fused_matches_dispatched_and_oracle(mesh8, name):
+def test_program_matches_oracle(mesh8, name):
     slots, steps = CANNED[name]()
     oracle = simulate_program([(s.msgs, s.attrs) for s in steps],
                               _init_np(slots, P_MESH))
-    fused, led_f, _ = _run_trace_on_mesh(mesh8, slots, steps,
-                                         compiled=True)
-    disp, led_d, _ = _run_trace_on_mesh(mesh8, slots, steps,
-                                        compiled=False)
+    vals, ledger, ctx = _run_trace_on_mesh(mesh8, slots, steps)
     for s in slots:
-        assert (fused[s.sid] == oracle[s.sid]).all(), (name, s.sid)
-        assert (fused[s.sid] == disp[s.sid]).all(), (name, s.sid)
-    # ledger bit-for-bit: fusion must not change a single cost field
-    assert led_f == led_d, name
-    assert len(led_f) >= 1
+        assert (vals[s.sid] == oracle[s.sid]).all(), (name, s.sid)
+    # the ledger is the program's planned costs, field for field
+    expected = ctx.last_program.ledger_costs([st.label for st in steps],
+                                             canonical_order(steps))
+    assert ledger == expected, name
+    assert len(ledger) >= 1
 
 
 def test_compiled_entry_created_and_replayed(mesh8):
-    """10 replays of one recorded program: ONE compiled artifact,
-    called once per replay — the XLA computation is built once and the
-    per-replay Python work is a cache lookup + call."""
+    """10 replays of one recorded program in one trace: the program
+    cache builds it once (1 miss) and serves the other 9 replays (9
+    hits), and every replay moves the data."""
     pc, pgc = lpf.PlanCache(), lpf.ProgramCache()
     box = {}
 
@@ -176,43 +167,31 @@ def test_compiled_entry_created_and_replayed(mesh8):
         ctx.resize_message_queue(2 * ctx.p)
         a = ctx.register_global("a", jnp.arange(4.0) + ctx.pid)
         b = ctx.register_global("b", jnp.zeros(8))
-        for _i in range(10):
+        outs = []
+        for i in range(10):
+            ctx.write(a, jnp.arange(4.0) + ctx.pid + 100 * i)
             with ctx.program():
                 ctx.put(a, b, to=lambda s_: (s_ + 1) % ctx.p, size=4)
                 ctx.sync(label="shift")
                 ctx.put(a, b, to=lambda s_: (s_ + 2) % ctx.p, dst_off=4,
                         size=4)
                 ctx.sync(label="shift2")
+            outs.append(ctx.value(b))
         box["stats"] = ctx.cache_stats
-        return ctx.value(b)
+        return jnp.stack(outs)
 
     fn = jax.jit(compat.shard_map(wrapped, mesh=mesh8, in_specs=(P(),),
-                                  out_specs=P("x"), check_vma=False))
-    out = np.asarray(fn(jnp.zeros(1))).reshape(8, 8)
-    for d in range(8):
-        np.testing.assert_allclose(out[d, :4], np.arange(4.0) + (d - 1) % 8)
-        np.testing.assert_allclose(out[d, 4:], np.arange(4.0) + (d - 2) % 8)
+                                  out_specs=P(None, "x"), check_vma=False))
+    out = np.asarray(fn(jnp.zeros(1))).reshape(10, 8, 8)
+    for i in range(10):
+        for d in range(8):
+            np.testing.assert_allclose(
+                out[i, d, :4], np.arange(4.0) + (d - 1) % 8 + 100 * i)
+            np.testing.assert_allclose(
+                out[i, d, 4:], np.arange(4.0) + (d - 2) % 8 + 100 * i)
     assert box["stats"]["program"].misses == 1
-    assert len(pgc._compiled) == 1
-    (cp,) = [cp for per_axes in pgc._compiled.values()
-             for cp in per_axes.values()]
-    assert cp.n_calls == 10
-
-
-def test_compile_opt_out_env(mesh8, monkeypatch):
-    """LPF_COMPILE_PROGRAMS=0 restores per-superstep dispatch."""
-    monkeypatch.setenv("LPF_COMPILE_PROGRAMS", "0")
-    slots, steps = fft_redistribute_trace()
-    pgc = lpf.ProgramCache()
-    vals, _, ctx = _run_trace_on_mesh(mesh8, slots, steps, compiled=None,
-                                      program_cache=pgc)
-
-    oracle = simulate_program([(s.msgs, s.attrs) for s in steps],
-                              _init_np(slots, P_MESH))
-    for s in slots:
-        assert (vals[s.sid] == oracle[s.sid]).all()
-    assert not ctx.compile_programs
-    assert len(pgc._compiled) == 0
+    assert box["stats"]["program"].hits == 9
+    assert len(pgc) == 1
 
 
 # ---------------------------------------------------------------------------
@@ -321,87 +300,3 @@ def test_compile_loop_argument_validation(mesh8):
                                       out_specs=P(), check_vma=False))
         with pytest.raises(Exception, match="compile_loop|collect"):
             fn(jnp.zeros(1))
-
-
-# ---------------------------------------------------------------------------
-# graceful degradation: compiled -> dispatched fallback + quarantine
-# ---------------------------------------------------------------------------
-
-def test_compile_failure_falls_back_to_dispatched(mesh8):
-    """An injected whole-program compilation failure degrades to the
-    dispatched ``execute_schedule`` path: values AND ledger bit-for-bit
-    identical to both the clean compiled run and the oracle, the
-    (key, axes) is quarantined, and ``compile_fallbacks`` counts it."""
-    from repro.runtime import faults
-
-    slots, steps = fft_redistribute_trace()
-    oracle = simulate_program([(s.msgs, s.attrs) for s in steps],
-                              _init_np(slots, P_MESH))
-    clean_vals, clean_led, _ = _run_trace_on_mesh(
-        mesh8, slots, steps, compiled=True)
-
-    pgc = lpf.ProgramCache()
-    with faults.inject(faults.FaultPlan.parse("compile@0x-1")) as inj:
-        vals, led, ctx = _run_trace_on_mesh(
-            mesh8, slots, steps, compiled=True, program_cache=pgc)
-    assert inj.fired, "the compile seam never fired"
-    for s in slots:
-        assert (vals[s.sid] == oracle[s.sid]).all()
-        assert (vals[s.sid] == clean_vals[s.sid]).all()
-    assert led == clean_led
-    assert pgc.stats.compile_fallbacks == 1
-    assert len(pgc._compiled) == 0
-    (key,) = pgc._programs.keys()
-    assert pgc.compile_quarantined(key, ("x",))
-
-
-def test_quarantine_skips_compile_on_replay(mesh8):
-    """After a compile failure quarantines the signature, replays go
-    straight to the dispatched path: the compile seam is never
-    consulted again (no repeated doomed compiles), and the fallback
-    counter stays at one."""
-    from repro.runtime import faults
-
-    slots, steps = fft_redistribute_trace()
-    pc, pgc = lpf.PlanCache(), lpf.ProgramCache()
-    with faults.inject(faults.FaultPlan.parse("compile@0x-1")) as inj:
-        vals1, led1, _ = _run_trace_on_mesh(
-            mesh8, slots, steps, compiled=True,
-            plan_cache=pc, program_cache=pgc)
-        fired_after_first = len(inj.fired)
-        vals2, led2, _ = _run_trace_on_mesh(
-            mesh8, slots, steps, compiled=True,
-            plan_cache=pc, program_cache=pgc)
-    assert fired_after_first == 1
-    # the replay hit the quarantine before compile_program ran: the
-    # forever-armed compile event had no second invocation to fire on
-    assert len(inj.fired) == 1
-    assert inj.counts["compile"] == 1
-    assert pgc.stats.compile_fallbacks == 1
-    for s in slots:
-        assert (vals1[s.sid] == vals2[s.sid]).all()
-    assert led1 == led2
-
-
-def test_lpf_errors_never_degraded_around(mesh8):
-    """The ladder only degrades around *foreign* failures: an LPF error
-    raised during compilation (here: a capacity error injected at the
-    compile seam's position via a monkeypatched compile_program) must
-    propagate, not fall back."""
-    import repro.core.context as context_mod
-
-    slots, steps = fft_redistribute_trace()
-    pgc = lpf.ProgramCache()
-    orig = context_mod.compile_program
-
-    def boom(*a, **k):
-        raise lpf.LPFFatalError("contract violation during lowering")
-
-    context_mod.compile_program = boom
-    try:
-        with pytest.raises(Exception, match="contract violation"):
-            _run_trace_on_mesh(mesh8, slots, steps, compiled=True,
-                               program_cache=pgc)
-    finally:
-        context_mod.compile_program = orig
-    assert pgc.stats.compile_fallbacks == 0

@@ -6,8 +6,7 @@ Three layers under test:
     determinism, one counting point per seam, zero-fault transparency;
   * the degradation ladder — persist I/O retry -> disk_errors ->
     memory-only mode, the in-memory poison set for undeletable corrupt
-    entries, compiled->dispatched fallback (covered in
-    test_compiled_program.py), and with_capacity's resize-and-retry;
+    entries, and with_capacity's resize-and-retry;
   * the supervisor — transient errors absorbed via checkpoint-restore
     with a bounded restart budget, fatal errors propagated unchanged.
 """
@@ -95,18 +94,19 @@ def test_capacity_error_structured_fields():
 # ---------------------------------------------------------------------------
 
 def test_plan_spec_roundtrip():
-    spec = ("persist_save@0;persist_load@1x2:bitflip;compile@0x-1;"
+    spec = ("persist_save@0;persist_load@1x2:bitflip;straggler@0x-1;"
             "straggler@2=0.005;capacity@1x3")
     plan = FaultPlan.parse(spec)
     assert plan.spec() == spec
     assert FaultPlan.parse(plan.spec()).spec() == spec
-    assert plan.seams() == ("capacity", "compile", "persist_load",
-                            "persist_save", "straggler")
+    assert plan.seams() == ("capacity", "persist_load", "persist_save",
+                            "straggler")
 
 
 @pytest.mark.parametrize("bad", [
     "nosuchseam@0", "persist_save@-1", "persist_save@0x0",
-    "persist_save@0:nosuchmode", "compile", "compile@", "@0",
+    "persist_save@0:nosuchmode", "straggler", "capacity@", "@0",
+    "compile@0",
 ])
 def test_plan_rejects_malformed(bad):
     with pytest.raises(ValueError):
@@ -114,7 +114,7 @@ def test_plan_rejects_malformed(bad):
 
 
 def test_random_plans_are_seed_deterministic():
-    seams = ("compile", "straggler", "capacity")
+    seams = ("persist_save", "straggler", "capacity")
     specs = [FaultPlan.random(seed, seams=seams).spec()
              for seed in range(50)]
     again = [FaultPlan.random(seed, seams=seams).spec()
@@ -127,13 +127,13 @@ def test_random_plans_are_seed_deterministic():
 
 
 def test_event_due_semantics():
-    one = FaultEvent(seam="compile", at=2)
+    one = FaultEvent(seam="capacity", at=2)
     assert [one.due(i) for i in range(5)] == [False, False, True, False,
                                               False]
-    rep = FaultEvent(seam="compile", at=1, repeat=2)
+    rep = FaultEvent(seam="capacity", at=1, repeat=2)
     assert [rep.due(i) for i in range(5)] == [False, True, True, False,
                                               False]
-    forever = FaultEvent(seam="compile", at=3, repeat=-1)
+    forever = FaultEvent(seam="straggler", at=3, repeat=-1)
     assert [forever.due(i) for i in range(6)] == [False] * 3 + [True] * 3
 
 
@@ -145,9 +145,9 @@ def test_unarmed_seams_are_noops():
 
 
 def test_inject_restores_previous_injector():
-    outer = faults.arm(FaultPlan.parse("compile@50"))
+    outer = faults.arm(FaultPlan.parse("capacity@50"))
     try:
-        with faults.inject(FaultPlan.parse("compile@60")) as inner:
+        with faults.inject(FaultPlan.parse("capacity@60")) as inner:
             assert faults.active() is inner
         assert faults.active() is outer
     finally:
@@ -563,11 +563,27 @@ def test_chaos_smoke_warm_start_plans():
     baselines = {}
     for workload, spec in SMOKE_PLANS:
         if workload != "warm_start":
-            continue                 # mesh workloads run in the chaos tier
+            continue                 # mesh workloads: the test below
         verdict, detail = _run_one(workload, FaultPlan.parse(spec),
                                    baselines)
         assert verdict in ("identical", "classified"), \
             (workload, spec, verdict, detail)
+
+
+MESH_SMOKE_PLANS = [(w, spec) for w, spec in SMOKE_PLANS
+                    if w in ("bucketed_sync", "decode")]
+
+
+@pytest.mark.parametrize("workload,spec", MESH_SMOKE_PLANS,
+                         ids=[f"{w}-{spec}" for w, spec in MESH_SMOKE_PLANS])
+def test_chaos_smoke_mesh_plans(workload, spec):
+    """Each seam a recorded program on the host mesh can reach
+    (straggler, capacity) leaves the result identical to the fault-free
+    run or raises a classified error."""
+    from repro.runtime.faults import _run_one
+    verdict, detail = _run_one(workload, FaultPlan.parse(spec), {})
+    assert verdict in ("identical", "classified"), \
+        (workload, spec, verdict, detail)
 
 
 def test_zero_fault_path_is_transparent(tmp_path):
@@ -583,5 +599,5 @@ def test_zero_fault_path_is_transparent(tmp_path):
     blob1 = (tmp_path / "a" / entry_filename(k1)).read_bytes()
     blob2 = (tmp_path / "b" / entry_filename(k2)).read_bytes()
     assert blob1 == blob2                        # byte-identical entries
-    assert c1.stats.disk_errors == 0 and c1.stats.compile_fallbacks == 0
+    assert c1.stats.disk_errors == 0
     assert dataclasses.asdict(c1.stats) == dataclasses.asdict(c2.stats)

@@ -656,8 +656,8 @@ def test_canonical_tie_break_is_structural():
 def test_program_cache_lru_semantics():
     """Hits refresh recency (move_to_end) and eviction counts match:
     insert maxsize+2 distinct programs while touching the first — the
-    hot entry survives, the two coldest leave, and any compiled
-    artifact leaves with its program."""
+    hot entry survives, the two coldest leave, and each certificate
+    leaves with its program."""
     cache = ProgramCache(maxsize=4)
     progs = []
     for k in range(6):
@@ -673,14 +673,12 @@ def test_program_cache_lru_semantics():
             assert cache.get_or_build(progs[0], 4, MACHINE) is not None
         prog, key = cache.get_or_build_keyed(steps, 4, MACHINE)
         assert cache.certify(key, steps).ok
-        cache.set_compiled(key, ("x",), object())
         progs.append(steps)
     assert cache.stats.evictions == 2
     # the hot entry survived 6 inserts into maxsize=4 ...
     before = cache.stats.misses
     cache.get_or_build(progs[0], 4, MACHINE)
     assert cache.stats.misses == before          # hit, not rebuild
-    # ... and the evicted programs took their compiled artifacts along
-    # (and their verifier certificates)
-    assert len(cache._compiled) == len(cache._programs) == 4
+    # ... and the evicted programs took their verifier certificates along
+    assert len(cache._programs) == 4
     assert len(cache._certs) == 4

@@ -402,7 +402,6 @@ def test_pinning_is_observable_in_cache_metrics():
     assert m["program_entries"] == 1
     assert m["program_memory_only"] == 0
     assert "program_disk_errors" in m
-    assert "program_compile_fallbacks" in m
 
 
 # The real ProgramDecodeEngine (XLA-compiling) lives in the slow tier:
