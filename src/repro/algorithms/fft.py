@@ -41,9 +41,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import (LPFContext, LPF_SYNC_DEFAULT, SyncAttributes, exec_,
-                        hook, span)
-from jax.sharding import PartitionSpec as P
+from repro.core import (LPFContext, LPF_SYNC_DEFAULT, SyncAttributes, compat,
+                        exec_, span)
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 __all__ = ["bsp_fft_spmd", "bsp_fft", "fft_flops", "fft_h_bytes"]
 
@@ -72,11 +72,23 @@ def _local_fft(x: jnp.ndarray, use_kernel: bool) -> jnp.ndarray:
     return jnp.fft.fft(x)
 
 
+def _strided_slices(x: jnp.ndarray, p: int) -> list:
+    """``x[s::p]`` for each ``s``, as strided slices (``lax.slice`` with a
+    stride): a step-indexed ``x[s::p]`` lowers to a gather, and an
+    ``[n/p, p]`` view pads its p-wide minor dimension to a full TPU lane
+    tile, a 32 GiB buffer for complex64 at n = 2^28."""
+    n = x.shape[0]
+    return [jax.lax.slice(x, (s,), (n,), (p,)) for s in range(p)]
+
+
 @functools.partial(jax.jit, static_argnums=1)
 def _strided_layout(x: jnp.ndarray, p: int) -> jnp.ndarray:
-    n = x.shape[0]
-    return jnp.concatenate([jax.lax.slice(x, (s,), (n,), (p,))
-                            for s in range(p)])
+    return jnp.concatenate(_strided_slices(x, p))
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _strided_parts(x: jnp.ndarray, p: int) -> tuple:
+    return tuple(_strided_slices(x, p))
 
 
 def cyclic_layout(x, p: int):
@@ -180,13 +192,26 @@ def _fft_exec_spmd(n: int, p: int, ordered: bool, use_kernel: bool,
     """``bsp_fft``'s SPMD function, one per set of static parameters:
     ``exec_`` keys its compiled program on the function."""
 
-    def spmd(ctx, s, pp, xt):
-        xl = xt.reshape(pp, n // pp)[s]
+    def spmd(ctx, s, pp, xl):
         return bsp_fft_spmd(ctx, xl, n, ordered=ordered,
                             use_kernel=use_kernel, attrs=attrs,
                             inverse=inverse)
 
     return spmd
+
+
+def _scatter_parts(parts: tuple, sharding: NamedSharding) -> jax.Array:
+    """The array whose shard on each device of ``sharding`` (an ``n``-
+    vector sharded in ``len(parts)`` blocks) is block ``s``'s part,
+    ``parts[s]``: each part is sent to its own devices alone, in one
+    batched transfer; a part already on its device stays there."""
+    npp = parts[0].shape[0]
+    n = npp * len(parts)
+    index = sharding.addressable_devices_indices_map((n,))
+    devices = list(index)
+    shards = jax.device_put([parts[index[d][0].start // npp]
+                             for d in devices], devices)
+    return jax.make_array_from_single_device_arrays((n,), sharding, shards)
 
 
 def bsp_fft(mesh: jax.sharding.Mesh, x: jnp.ndarray, *,
@@ -196,21 +221,36 @@ def bsp_fft(mesh: jax.sharding.Mesh, x: jnp.ndarray, *,
             return_ledger: bool = False):
     """Whole-array driver: ``lpf_exec`` the immortal FFT over ``mesh``.
 
-    ``x`` is the full (host) vector; it is scattered cyclically, the SPMD
-    FFT runs, and the naturally-ordered result is gathered back.
+    ``x`` is the full vector.  Process ``s`` of the ``p`` over ``axes``
+    receives its cyclic slice ``x[s::p]``, as its shard of an
+    ``n``-vector sharded ``P(axes)``, and nothing else of ``x``; the
+    SPMD FFT runs, and the naturally-ordered result comes back sharded
+    ``P(axes)``.
+
+    Where ``x`` is a ``jax.Array`` on one device and no transformation
+    is tracing the call, that device lays out the ``p`` slices as ``p``
+    buffers (span ``lpf.fft.layout``) and sends each to its process's
+    devices alone (span ``lpf.fft.scatter``).  Any other input (host
+    memory, an array spread over devices, a caller's tracer) is laid out
+    as one vector, and JAX or the partitioner moves its blocks.  At
+    ``p == 1`` there is no layout.
     """
     axes = tuple(axes) if axes is not None else tuple(mesh.axis_names)
     p = int(np.prod([mesh.shape[a] for a in axes]))
     n = int(x.shape[0])
-    # cyclic layout, pid-major, from strided slices (lax.slice with a
-    # stride): a step-indexed x[s::p] lowers to a gather, and an
-    # [n/p, p] view pads its p-wide minor dimension to a full TPU lane
-    # tile, a 32 GiB buffer for complex64 at n = 2^28
-    with span("lpf.fft.layout"):
-        xc = cyclic_layout(x, p)
+    spec = P(axes)
+    if (p > 1 and not compat.tracing() and isinstance(x, jax.Array)
+            and len(x.sharding.device_set) == 1):
+        with span("lpf.fft.layout"):
+            parts = _strided_parts(x, p)
+        with span("lpf.fft.scatter"):
+            xc = _scatter_parts(parts, NamedSharding(mesh, spec))
+    else:
+        with span("lpf.fft.layout"):
+            xc = cyclic_layout(x, p)
     spmd = _fft_exec_spmd(n, p, ordered, use_kernel, attrs, inverse)
-    out = exec_(mesh, spmd, jnp.asarray(xc), axes=axes,
-                out_specs=P(axes), return_ledger=return_ledger)
+    out = exec_(mesh, spmd, jnp.asarray(xc), axes=axes, in_specs=spec,
+                out_specs=spec, return_ledger=return_ledger)
     if return_ledger:
         out, ledger = out
     y = out.reshape(-1)

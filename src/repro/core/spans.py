@@ -29,6 +29,9 @@ The program's spans:
 * ``lpf.flush``: a recorded trace's program fetch or build,
   certification, compilation and lowering;
 * ``lpf.fft.layout``: ``bsp_fft``'s cyclic layout of its input;
+* ``lpf.fft.scatter``: ``bsp_fft`` sending each process its own cyclic
+  slice, from the one device its input is on (once per eager call over
+  more than one process with such an input);
 * ``lpf.pagerank.upload``: ``lpf_pagerank``'s upload of the partitioned
   graph.
 """

@@ -6,9 +6,11 @@ with no chip attached, and refuses what the chip would refuse — shapes
 it cannot lay out, operations it cannot lower, more VMEM than a kernel
 may use.  The interpret-mode tests cannot see any of that.  One more
 test compiles ``bsp_fft``'s cyclic layout, which is no kernel, to see
-that it runs as strided slices on the chip rather than as a gather, and
-one compiles the benchmark's Moonlight training step, which is no kernel
-either, to see that it fits one chip's memory.
+that it runs as strided slices on the chip rather than as a gather; one
+compiles ``bsp_fft``'s four-chip program, to see that each chip takes
+only its quarter of the input; and one compiles the benchmark's
+Moonlight training step, which is no kernel either, to see that it fits
+one chip's memory.
 
 Widths are the ones ``chip_smoke.py`` runs: flash attention at
 llama3.2-1b (H=32, Hkv=8, S=2048, D=64, bf16), ``fft_stage`` on
@@ -112,6 +114,27 @@ def test_fft_cyclic_layout_compiles_without_gather(one_chip):
     compiled = _strided_layout.lower(x, 4).compile()
     assert not re.search(r"\bgather\(", compiled.as_text())
     assert compiled.memory_analysis().temp_size_in_bytes <= 3 * 2**30
+
+
+def test_fft_p4_program_takes_one_cyclic_quarter_per_chip(topo):
+    """``bsp_fft``'s program over the four chips at 2^28 points, as
+    ``exec_`` keeps it for an input sharded ``P("x")`` (the placement
+    ``bsp_fft`` hands it): each chip's parameter is its own quarter,
+    ``c64[2^26]`` (512 MiB), and no all-gather rebuilds the input."""
+    from repro.algorithms.fft import _fft_exec_spmd
+    from repro.core import LPF_SYNC_DEFAULT, exec_
+
+    n, spec = 1 << 28, PartitionSpec("x")
+    mesh = Mesh(np.array(topo.devices), ("x",))
+    x = _sds(NamedSharding(mesh, spec), (n,), jnp.complex64)
+    spmd = _fft_exec_spmd(n, 4, True, False, LPF_SYNC_DEFAULT, False)
+    compiled = jax.jit(lambda v: exec_(mesh, spmd, v, in_specs=spec,
+                                       out_specs=spec)).lower(x).compile()
+    text = compiled.as_text()
+    entry = text.split("ENTRY", 1)[1]
+    assert re.search(r"= c64\[67108864\]\S* parameter\(0\)", entry)
+    assert compiled.memory_analysis().argument_size_in_bytes <= 2**29
+    assert "all-gather" not in text
 
 
 def test_moonlight_train_step_fits_one_chip(topo):

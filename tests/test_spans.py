@@ -98,19 +98,24 @@ EXEC = {"lpf.exec.trace": 1, "lpf.exec.lower": 1, "lpf.exec.compile": 1,
 #: spans of a driver's first call, by driver and number of processes
 PER_CALL = {
     ("fft", 1): {**EXEC, "lpf.fft.layout": 1},
-    ("fft", 4): {**EXEC, "lpf.fft.layout": 1, "lpf.sync": 2,
-                 "lpf.flush": 2},
+    ("fft", 4): {**EXEC, "lpf.fft.layout": 1, "lpf.fft.scatter": 1,
+                 "lpf.sync": 2, "lpf.flush": 2},
     ("pagerank", 1): {**EXEC, "lpf.pagerank.upload": 1, "lpf.sync": 1,
                       "lpf.flush": 1},
     ("pagerank", 4): {**EXEC, "lpf.pagerank.upload": 1, "lpf.sync": 5,
                       "lpf.flush": 3},
 }
 
-#: spans of every later call, which runs the executable exec_ kept
+#: spans of every later call, which runs the executable exec_ kept;
+#: bsp_fft scatters its layout over the processes when there are several
 WARM = {
-    "fft": {"lpf.exec.hit": 1, "lpf.exec.run": 1, "lpf.fft.layout": 1},
-    "pagerank": {"lpf.exec.hit": 1, "lpf.exec.run": 1,
-                 "lpf.pagerank.upload": 1},
+    ("fft", 1): {"lpf.exec.hit": 1, "lpf.exec.run": 1, "lpf.fft.layout": 1},
+    ("fft", 4): {"lpf.exec.hit": 1, "lpf.exec.run": 1, "lpf.fft.layout": 1,
+                 "lpf.fft.scatter": 1},
+    ("pagerank", 1): {"lpf.exec.hit": 1, "lpf.exec.run": 1,
+                      "lpf.pagerank.upload": 1},
+    ("pagerank", 4): {"lpf.exec.hit": 1, "lpf.exec.run": 1,
+                      "lpf.pagerank.upload": 1},
 }
 
 #: each driver's factory of the SPMD function exec_ keys on
@@ -136,7 +141,7 @@ def test_driver_spans_per_call(events, driver, p, warm):
     if warm:
         call()                             # plans and compiles once
         del events[:]
-        calls, expect = 2, WARM[driver]
+        calls, expect = 2, WARM[(driver, p)]
     else:
         # a new SPMD function, for which exec_ has kept nothing
         FACTORY[driver].cache_clear()
